@@ -11,6 +11,7 @@ from benchmarks import common
 from repro.distributed import ctx
 from repro.kernels import dispatch, ref
 from repro.kernels.flash_attention import masked_tile_fraction
+from repro.launch.mesh import make_mesh
 
 
 def run() -> list:
@@ -66,7 +67,7 @@ def run() -> list:
         mesh_shape = (n_dev, 1)      # batch over data
     else:
         mesh_shape = (1, 1)          # trivial mesh, same kernels
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     with ctx.use_mesh(mesh):
         sh_fwd = jax.jit(lambda q, k, v: dispatch.flash_attention(
             q, k, v, causal=True, backend="pallas_shard_map"))

@@ -1,7 +1,7 @@
 """Chunked-prefill benchmark: fused append path vs the masked-sdpa prefix
 baseline (the PR-4 path this PR deletes).
 
-Three legs, all landing in a root-level ``BENCH_prefill.json`` (uploaded
+Two legs, both landing in a root-level ``BENCH_prefill.json`` (uploaded
 as a CI artifact — the start of the per-PR prefill perf trajectory):
 
   * **measured** — multi-chunk prefill tokens/s through the real engine
@@ -16,9 +16,10 @@ as a CI artifact — the start of the per-PR prefill perf trajectory):
     f32 (C, Sk) scores + Hq-repeated K/V streams every chunk, the fused
     kernel keeps score tiles in VMEM — the ratio that governs the TPU
     roofline.
-  * **serve_demo** — a 3-chunk prompt-2048 serve run on a 2-device host
-    mesh (subprocess with forced host devices): the dispatch decision log
-    must show every chunk on a pallas append arm.
+
+The multi-device decode-cp serving path is exercised by
+``tests/test_serve_engine.py::test_engine_decode_cp_smoke`` and, on four
+chips, by ``python chip_smoke.py --chips 4``.
 
   PYTHONPATH=src python -m benchmarks.run --quick
 """
@@ -26,9 +27,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -118,39 +116,7 @@ def _prefill_tok_s(cfg, params, prompt_len: int, chunk: int,
     return prompt_len * 1e6 / us
 
 
-def _serve_demo(timeout_s: int = 420) -> Optional[dict]:
-    """3-chunk prompt-2048 serve run on a forced 2-device host mesh; the
-    returned record carries the dispatch decision summary."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        " --xla_force_host_platform_device_count=2").strip()
-    env["PYTHONPATH"] = os.path.join(ROOT, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, "-m", "repro.launch.serve",
-           "--arch", "stablelm-1.6b", "--slots", "1", "--requests", "1",
-           "--prompt-range", "2048,2048", "--gen-range", "2,2",
-           "--cache-len", "2304", "--chunk", "768", "--greedy",
-           "--decode-cp"]
-    try:
-        out = subprocess.run(cmd, capture_output=True, text=True,
-                             timeout=timeout_s, env=env, cwd=ROOT)
-        rec = json.loads(out.stdout.strip().splitlines()[-1])
-    except Exception as e:  # noqa: BLE001 — demo leg degrades, not fails
-        return {"error": f"{type(e).__name__}: {e}"}
-    append_rows = [r for r in rec.get("kernel_dispatch", [])
-                   if r["op"] == "flash_append"]
-    n_chunks = 3
-    fused = sum(r["count"] for r in append_rows
-                if r["backend"].startswith("pallas"))
-    return {
-        "prompt": 2048, "chunk": 768, "n_chunks": n_chunks,
-        "decode_layout": rec.get("decode_layout"),
-        "kernel_dispatch": rec.get("kernel_dispatch"),
-        "append_chunks_on_pallas": fused >= n_chunks,
-    }
-
-
-def run(*, arch: str = "stablelm-1.6b", demo: bool = True) -> list:
+def run(*, arch: str = "stablelm-1.6b") -> list:
     from repro.configs import get_config
     from repro.launch import traffic
     from repro.models import model as M
@@ -190,14 +156,6 @@ def run(*, arch: str = "stablelm-1.6b", demo: bool = True) -> list:
             "derived": f"tok_s={tok_f:.1f} vs_masked={tok_f / tok_m:.2f}x "
                        f"hbm_ratio={bm / bf:.1f}x"})
 
-    demo_rec = _serve_demo() if demo else None
-    if demo_rec is not None:
-        rows.append({
-            "name": "prefill_serve_demo_2048x3",
-            "us_per_call": 0.0,
-            "derived": "append_chunks_on_pallas="
-                       f"{demo_rec.get('append_chunks_on_pallas')}"})
-
     record = {
         "arch": cfg.name,
         "platform": jax.default_backend(),
@@ -209,7 +167,6 @@ def run(*, arch: str = "stablelm-1.6b", demo: bool = True) -> list:
                  "analytic_hbm ratio is the kernel's roofline term"),
         "measured": measured,
         "analytic_hbm": analytic,
-        "serve_demo": demo_rec,
     }
     with open(BENCH_JSON, "w") as f:
         json.dump(record, f, indent=1)

@@ -75,11 +75,8 @@ def main() -> None:
         import os
         sys.path.insert(0, os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
-        try:
-            from tools.audit import quick_summary
-            print(quick_summary(), flush=True)
-        except Exception as e:       # never let the audit sink the bench
-            print(f"audit,error,{e!r}", flush=True)
+        from tools.audit import quick_summary
+        print(quick_summary(), flush=True)
     else:
         only = args.only.split(",") if args.only else list(benches)
     print("name,us_per_call,derived")

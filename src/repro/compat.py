@@ -1,94 +1,21 @@
-"""Version-tolerant shims over JAX APIs that moved between releases.
+"""The one hook this repo needs that JAX has no public name for.
 
-The repo targets whatever JAX the image bakes in (currently 0.4.37) but is
-written against the newer public names; every drift goes through one helper
-here so call sites stay clean and a future JAX bump is a one-file change.
+The repo targets the installed JAX (0.9) and uses its public names
+directly (``pltpu.CompilerParams``, ``jax.set_mesh``, ``jax.shard_map``,
+``compiled.cost_analysis()``).  What is left here:
 
-Covered drifts:
-  * ``pltpu.CompilerParams``      — named ``TPUCompilerParams`` in <= 0.4.x.
-  * ``jax.sharding.set_mesh``     — absent in <= 0.4.x; ``Mesh`` itself is a
-    context manager there, and ``AbstractMesh`` needs no entry at all when
-    shardings are passed explicitly.
-  * ``AbstractMesh(...)``         — 0.4.x takes one tuple of (name, size)
-    pairs; newer JAX takes (axis_sizes, axis_names).
-  * trace-cache token             — jax has no public "fold this value into
-    the jit cache key" hook; ``set_trace_token`` rides the
-    ``mesh_context_manager`` config state: it participates in both the
-    python trace cache (``config.trace_context()``) and the C++ jit key
-    (``include_in_jit_key=True``), and — unlike the xla_metadata slot,
-    which JaxprEqnContext managers rewrite mid-trace — it is only ever
-    written by ``Mesh.__enter__/__exit__``, so an appended token survives
-    a whole trace/lower block.  If the state ever disappears the shim
-    degrades to a no-op and the dispatch layer falls back to its
-    documented trace-cache caveat.
+  * trace-cache token — jax has no public "fold this value into the jit
+    cache key" hook; ``set_trace_token`` rides the ``mesh_context_manager``
+    config state: it participates in both the python trace cache
+    (``config.trace_context()``) and the C++ jit key
+    (``include_in_jit_key=True``), and it is only ever written by
+    ``Mesh.__enter__/__exit__``, which this repo never uses (meshes are
+    installed with ``jax.set_mesh``), so an appended token survives a whole
+    trace/lower block.  If the state ever disappears the shim degrades to a
+    no-op and the dispatch layer falls back to its documented trace-cache
+    caveat.
 """
 from __future__ import annotations
-
-import contextlib
-from typing import Sequence, Tuple
-
-import jax
-from jax.experimental.pallas import tpu as pltpu
-
-_TPU_COMPILER_PARAMS_CLS = getattr(
-    pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` under either name."""
-    return _TPU_COMPILER_PARAMS_CLS(**kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Prefers ``jax.sharding.set_mesh`` / ``jax.set_mesh`` (newer JAX).  On
-    0.4.x a concrete ``Mesh`` is its own context manager; an
-    ``AbstractMesh`` has no context to enter — explicit NamedShardings
-    carry it — so we no-op.
-
-    ``Mesh.__enter__``/``__exit__`` rebuild the trace-token carrier state
-    from the mesh stack, which would silently drop a dispatch token
-    appended by ``ctx.use_mesh``/``ctx.sharding_rules`` (and with it the
-    stale-trace protection), so this wrapper re-asserts the current
-    dispatch token after both transitions.
-    """
-    setter = getattr(jax.sharding, "set_mesh", None) or \
-        getattr(jax, "set_mesh", None)
-    if setter is not None:
-        inner = setter(mesh)
-    elif hasattr(mesh, "__enter__"):
-        inner = mesh
-    else:
-        inner = contextlib.nullcontext(mesh)
-    if _token_provider is None:
-        return inner
-    return _reassert_token_around(inner)
-
-
-@contextlib.contextmanager
-def _reassert_token_around(inner):
-    with inner as m:
-        prev = set_trace_token(_token_provider())
-        try:
-            yield m
-        finally:
-            restore_trace_token(prev)
-    # the mesh exit rebuilt the carrier from its stack, dropping tokens
-    # appended by enclosing ctx managers — re-assert the current state
-    set_trace_token(_token_provider())
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict.
-
-    Depending on jax/XLA version this returns a dict or a one-element list
-    of per-module dicts; normalize to the (possibly empty) dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
 
 
 def _trace_token_state():
@@ -105,15 +32,6 @@ def _trace_token_state():
 
 _NO_TOKEN = object()
 _TOKEN_TAG = "repro.dispatch"
-_token_provider = None
-
-
-def register_trace_token_provider(fn) -> None:
-    """``fn() -> token | None`` returning the current dispatch state;
-    ``set_mesh`` uses it to re-assert the token across Mesh transitions
-    (registered by ``repro.distributed.ctx`` at import)."""
-    global _token_provider
-    _token_provider = fn
 
 
 def set_trace_token(token):
@@ -124,12 +42,11 @@ def set_trace_token(token):
     callable under a different dispatch mesh / rule set re-resolves kernel
     dispatch instead of replaying the stale trace.  The token is appended
     to the carrier state's previous value (a tuple) with any older
-    dispatch token stripped first — idempotent, so re-asserting after a
-    Mesh transition cannot stack stale entries.  ``token=None`` means "no
-    dispatch state": nothing is appended.  Returns an opaque previous
-    value — pass it back to :func:`restore_trace_token` on exit.  Degrades
-    to a no-op (returns ``_NO_TOKEN``) if the underlying jax state is
-    gone.
+    dispatch token stripped first — idempotent, so re-asserting cannot
+    stack stale entries.  ``token=None`` means "no dispatch state":
+    nothing is appended.  Returns an opaque previous value — pass it back
+    to :func:`restore_trace_token` on exit.  Degrades to a no-op (returns
+    ``_NO_TOKEN``) if the underlying jax state is gone.
     """
     cm = _trace_token_state()
     if cm is None:
@@ -147,14 +64,3 @@ def restore_trace_token(prev) -> None:
     cm = _trace_token_state()
     if cm is not None and prev is not _NO_TOKEN:
         cm.set_local(prev)
-
-
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """``AbstractMesh`` under both constructor signatures."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        pairs: Tuple[Tuple[str, int], ...] = tuple(
-            zip(axis_names, axis_sizes))
-        return AbstractMesh(pairs)

@@ -62,11 +62,6 @@ def _install_token():
     return compat.set_trace_token(dispatch_token())
 
 
-# compat.set_mesh re-asserts this token around Mesh context transitions
-# (Mesh.__enter__/__exit__ rebuild the carrier state and would drop it)
-compat.register_trace_token_provider(dispatch_token)
-
-
 def current_rules() -> Optional[Dict[str, jax.sharding.PartitionSpec]]:
     return getattr(_state, "rules", None)
 
@@ -108,7 +103,7 @@ def current_mesh():
 def use_mesh(mesh):
     """Install ``mesh`` as the kernel-dispatch target around trace/lower time.
 
-    Orthogonal to ``compat.set_mesh`` (which feeds jax's sharding machinery):
+    Orthogonal to ``jax.set_mesh`` (which feeds jax's sharding machinery):
     this one makes the mesh *visible* to the dispatch layer so it can
     shard_map the Pallas kernels over it and resolve the target platform,
     and folds the mesh into the jit cache key (see module docstring) so one

@@ -317,8 +317,8 @@ def _resolve(spec_tpl, mesh: Mesh, *, fsdp: bool = True):
         if s == "M":
             out.append("model")
         elif s == "F":
-            # newer jax canonicalizes P(('data',)) to P('data'); 0.4.x
-            # keeps the 1-tuple — emit the canonical bare name ourselves
+            # jax canonicalizes P(('data',)) to P('data'): emit the bare
+            # name so specs compare equal to jax's
             ax = d_ax if (fsdp and d_ax) else None
             out.append(ax[0] if isinstance(ax, tuple) and len(ax) == 1
                        else ax)

@@ -1,10 +1,13 @@
 """Pallas TPU decode attention: one query token per sequence vs a KV cache.
 
 The memory-bound phase of serving: each step streams the KV cache from HBM
-once.  Grid: (batch, kv_heads, n_kv_blocks) — all G query heads that share a
-KV head are packed into one (G x D) @ (D x block_k) MXU matmul per block, so
-GQA costs one cache read regardless of the query-head fan-out.  Online
-softmax state lives in VMEM scratch across the innermost KV dimension.
+once.  Grid: (batch, n_kv_blocks).  Each K/V block holds all Hkv heads of
+``block_k`` cache rows — the cache's own (B, L, Hkv, D) layout, which Mosaic
+accepts because the block spans the two minor dims whole — and the body
+walks the kv heads as static slices.  All G query heads that share a KV head
+are packed into one (G x D) @ (D x block_k) MXU matmul, so GQA costs one
+cache read regardless of the query-head fan-out.  Online softmax state lives
+in VMEM scratch across the innermost KV dimension.
 
 Positions are **per slot** (continuous batching): ``pos (B,)`` is each
 sequence's current decode position and ``kpos (B, L)`` the absolute position
@@ -34,14 +37,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels._interpret import default_interpret
 
 NEG = -1e30
+# scoped VMEM the attention kernels may claim; v5e has 128 MiB per core and
+# the compiler's default scope (16 MiB) is too small for all-head blocks
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, *refs,
-            block_k: int, n_k: int, scale: float, partials: bool,
+            n_heads: int, n_k: int, scale: float, partials: bool,
             quant: bool):
     if quant:
         ks_ref, vs_ref, *refs = refs
@@ -49,7 +54,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, *refs,
         acc_out_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref = refs
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
-    ik = pl.program_id(2)
+    ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -57,30 +62,41 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                         # (G, D)
-    k = k_ref[0, :, 0, :]                   # (bk, D)
-    v = v_ref[0, :, 0, :]                   # (bk, D)
-    if quant:
-        # dequant in VMEM: the HBM stream stays int8, the per-(row, head)
-        # f32 scales ((bk, 1) blocks) broadcast over the lane dim
-        k = k.astype(jnp.float32) * ks_ref[0, :, 0, :]
-        v = v.astype(jnp.float32) * vs_ref[0, :, 0, :]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    kpos = kpos_ref[0, :]                   # (bk,) — this row's slot map
+    kpos = kpos_ref[0]                      # (1, bk) — this row's slot map
     pos = pos_ref[pl.program_id(0)]         # this row's decode position
     valid = (kpos >= 0) & (kpos <= pos)
-    s = jnp.where(valid[None, :], s, NEG)
+    if quant:
+        # the HBM stream stays int8; the per-(row, head) scales arrive as
+        # a (bk, Hkv) block, transposed once so each head's scales are a
+        # (1, bk) lane row that scales its scores and probabilities
+        ks = ks_ref[0].T                    # (Hkv, bk)
+        vs = vs_ref[0].T
+    # the K/V block holds every kv head of bk cache rows, so the cache is
+    # streamed in its own (B, L, Hkv, D) layout; heads are static slices
+    for h in range(n_heads):
+        q = q_ref[0, h]                     # (G, D)
+        k = k_ref[0, :, h, :]               # (bk, D)
+        v = v_ref[0, :, h, :]               # (bk, D)
+        if quant:
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * ks[h:h + 1]             # q.(k8 * ks) == (q.k8) * ks
+        s = jnp.where(valid, s, NEG)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[h]                   # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+        pv = p * vs[h:h + 1] if quant else p  # p.(v8 * vs) == (p * vs).v8
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -88,13 +104,31 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, *refs,
             # unnormalized flash-decoding state; a fully-masked slice keeps
             # m=NEG, so its correction exp(m - pmax(m)) underflows to 0 and
             # the slice vanishes in the cross-shard combine
-            acc_out_ref[0, 0] = acc_ref[...]
-            m_out_ref[0, 0] = m_ref[...]
-            l_out_ref[0, 0] = l_ref[...]
+            acc_out_ref[0] = acc_ref[...]
+            m_out_ref[0] = m_ref[...]
+            l_out_ref[0] = l_ref[...]
         else:
             l_safe = jnp.maximum(l_ref[...], 1e-30)
-            o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]) \
-                .astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def kv_block_rows(length: int, hkv: int, d: int, itemsize: int,
+                  cap: int = 1024) -> int:
+    """Key rows per all-head K/V block: the largest multiple of 128 that
+    divides ``length``, is at most ``cap`` and keeps the block within
+    2 MiB of VMEM (the lanes pad D up to 128); 128 at least, since the
+    kpos block's lane dim must be a multiple of 128.  A ``length`` that
+    128 does not divide (dispatch sends those to jnp on a chip) gets its
+    largest power-of-two divisor up to ``cap``."""
+    if length % 128:
+        bk = min(cap, length)
+        while length % bk:
+            bk //= 2
+        return bk
+    row_bytes = hkv * max(d, 128) * itemsize
+    return max((bk for bk in range(128, min(cap, length) + 1, 128)
+                if length % bk == 0 and bk * row_bytes <= 2 << 20),
+               default=128)
 
 
 def _per_slot(kpos, pos, batch: int):
@@ -123,47 +157,50 @@ def _call(q, k_cache, v_cache, kpos, pos, *, block_k: int, partials: bool,
         interpret = default_interpret()
 
     qg = q.reshape(b, hkv, g, d)
-    kern = functools.partial(_kernel, block_k=bk, n_k=n_k, scale=d ** -0.5,
-                             partials=partials, quant=quant)
-    blk4 = pl.BlockSpec((1, 1, g, d), lambda b_, h, ik: (b_, h, 0, 0))
-    blk3 = pl.BlockSpec((1, 1, g), lambda b_, h, ik: (b_, h, 0))
+    kern = functools.partial(_kernel, n_heads=hkv, n_k=n_k,
+                             scale=d ** -0.5, partials=partials, quant=quant)
+    # Mosaic tiles the last two block dims: every block below either spans
+    # them whole or is (8, 128)-aligned there, so the caches keep their
+    # (B, L, Hkv, D) layout and kpos rides as (B, 1, L)
+    heads = pl.BlockSpec((1, hkv, g, d), lambda b_, ik: (b_, 0, 0, 0))
+    stats = pl.BlockSpec((1, hkv, g, 1), lambda b_, ik: (b_, 0, 0, 0))
     if partials:
-        out_specs = [blk4, blk3, blk3]
+        out_specs = [heads, stats, stats]
         out_shape = [jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
-                     jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
-                     jax.ShapeDtypeStruct((b, hkv, g), jnp.float32)]
+                     jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32)]
     else:
-        out_specs = blk4
+        out_specs = heads
         out_shape = jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype)
+    rows = pl.BlockSpec((1, bk, hkv, d), lambda b_, ik: (b_, ik, 0, 0))
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),   # pos (B,)
-        pl.BlockSpec((1, 1, g, d), lambda b_, h, ik: (b_, h, 0, 0)),
-        pl.BlockSpec((1, bk, 1, d), lambda b_, h, ik: (b_, ik, h, 0)),
-        pl.BlockSpec((1, bk, 1, d), lambda b_, h, ik: (b_, ik, h, 0)),
-        pl.BlockSpec((1, bk), lambda b_, h, ik: (b_, ik)),
+        heads,
+        rows,
+        rows,
+        pl.BlockSpec((1, 1, bk), lambda b_, ik: (b_, 0, ik)),
     ]
-    operands = [pos.astype(jnp.int32), qg, k_cache, v_cache, kpos]
+    operands = [pos.astype(jnp.int32), qg, k_cache, v_cache,
+                kpos.astype(jnp.int32)[:, None, :]]
     if quant:
-        # per-(row, head) f32 scales (B, L, Hkv, 1) ride next to the caches
-        in_specs += [
-            pl.BlockSpec((1, bk, 1, 1), lambda b_, h, ik: (b_, ik, h, 0)),
-            pl.BlockSpec((1, bk, 1, 1), lambda b_, h, ik: (b_, ik, h, 0)),
-        ]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # per-(row, head) f32 scales (B, L, Hkv, 1) ride next to the caches,
+        # their unit lane dim dropped (a free reshape)
+        scales = pl.BlockSpec((1, bk, hkv), lambda b_, ik: (b_, ik, 0))
+        in_specs += [scales, scales]
+        operands += [k_scale.astype(jnp.float32)[..., 0],
+                     v_scale.astype(jnp.float32)[..., 0]]
     return pl.pallas_call(
         kern,
-        grid=(b, hkv, n_k),
+        grid=(b, n_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
 
@@ -199,4 +236,4 @@ def decode_attention_partials(q, k_cache, v_cache, kpos, pos, *,
     acc, m, l = _call(q, k_cache, v_cache, kpos, pos, block_k=block_k,
                       partials=True, interpret=interpret,
                       k_scale=k_scale, v_scale=v_scale)
-    return acc, m, l
+    return acc, m[..., 0], l[..., 0]

@@ -59,7 +59,6 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed import ctx
 from repro.distributed.sharding import (AttnShardSpec, DecodeCPSpec,
@@ -68,7 +67,8 @@ from repro.distributed.sharding import (AttnShardSpec, DecodeCPSpec,
                                         rmsnorm_shard_spec)
 from repro.kernels import ref
 from repro.kernels.decode_attention import (_per_slot, decode_attention_fwd,
-                                            decode_attention_partials)
+                                            decode_attention_partials,
+                                            kv_block_rows)
 from repro.kernels.flash_attention import (
     flash_attention_append as flash_attention_append_fwd,
     flash_attention_fwd)
@@ -193,9 +193,9 @@ def _flash_fwd_call(q, k, v, causal, window, shard, interpret,
     if shard is None:
         return call(q, k, v)
     out_specs = (shard.qo, shard.lse) if save_residuals else shard.qo
-    return shard_map(call, mesh=shard.mesh,
-                     in_specs=(shard.qo, shard.kv, shard.kv),
-                     out_specs=out_specs, check_rep=False)(q, k, v)
+    return jax.shard_map(call, mesh=shard.mesh,
+                         in_specs=(shard.qo, shard.kv, shard.kv),
+                         out_specs=out_specs, check_vma=False)(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -218,11 +218,11 @@ def _flash_pallas_bwd(causal, window, shard, interpret, res, do):
                                    interpret=interpret)
     if shard is None:
         return call(q, k, v, o, lse, do)
-    return shard_map(call, mesh=shard.mesh,
-                     in_specs=(shard.qo, shard.kv, shard.kv, shard.qo,
-                               shard.lse, shard.qo),
-                     out_specs=(shard.qo, shard.kv, shard.kv),
-                     check_rep=False)(q, k, v, o, lse, do)
+    return jax.shard_map(call, mesh=shard.mesh,
+                         in_specs=(shard.qo, shard.kv, shard.kv, shard.qo,
+                                   shard.lse, shard.qo),
+                         out_specs=(shard.qo, shard.kv, shard.kv),
+                         check_vma=False)(q, k, v, o, lse, do)
 
 
 _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
@@ -343,7 +343,7 @@ def _append_call(q, k, v, kpos, ks, vs, pos0, window, kpos_linear, shard,
                  interpret):
     def call(q, k, v, kpos, ks=None, vs=None):
         bq = _flash_blocks(q.shape[1])
-        bk = _flash_blocks(k.shape[1])
+        bk = kv_block_rows(*k.shape[1:], k.dtype.itemsize, cap=512)
         return flash_attention_append_fwd(q, k, v, kpos, pos0=pos0,
                                           window=window, block_q=bq,
                                           block_k=bk,
@@ -354,14 +354,14 @@ def _append_call(q, k, v, kpos, ks, vs, pos0, window, kpos_linear, shard,
         return call(q, k, v, kpos, ks, vs)
     base = (shard.qo, shard.kv, shard.kv, shard.kpos_decode)
     if ks is None:
-        return shard_map(call, mesh=shard.mesh, in_specs=base,
-                         out_specs=shard.qo, check_rep=False)(q, k, v, kpos)
+        return jax.shard_map(call, mesh=shard.mesh, in_specs=base,
+                             out_specs=shard.qo, check_vma=False)(q, k, v, kpos)
     # the rank-4 scale tensors (B, Sk, Hkv, 1) shard exactly like the
     # caches they annotate
-    return shard_map(call, mesh=shard.mesh,
-                     in_specs=base + (shard.kv, shard.kv),
-                     out_specs=shard.qo,
-                     check_rep=False)(q, k, v, kpos, ks, vs)
+    return jax.shard_map(call, mesh=shard.mesh,
+                         in_specs=base + (shard.kv, shard.kv),
+                         out_specs=shard.qo,
+                         check_vma=False)(q, k, v, kpos, ks, vs)
 
 
 def _append_dense(q, k, v, kpos, pos0, window):
@@ -497,10 +497,7 @@ def flash_attention_append(q, k, v, kpos, *, pos0: int,
 @functools.partial(jax.jit, static_argnames=("shard", "interpret"))
 def _decode_call(q, k_cache, v_cache, kpos, pos, ks, vs, shard, interpret):
     def call(q, kc, vc, kpos, pos, ks=None, vs=None):
-        length = kc.shape[1]
-        bk = min(1024, length)
-        while length % bk:
-            bk //= 2
+        bk = kv_block_rows(*kc.shape[1:], kc.dtype.itemsize)
         return decode_attention_fwd(q, kc, vc, kpos, pos, block_k=bk,
                                     interpret=interpret,
                                     k_scale=ks, v_scale=vs)
@@ -509,15 +506,15 @@ def _decode_call(q, k_cache, v_cache, kpos, pos, ks, vs, shard, interpret):
     base = (shard.q_decode, shard.kv, shard.kv, shard.kpos_decode,
             shard.pos_decode)
     if ks is None:
-        return shard_map(call, mesh=shard.mesh, in_specs=base,
-                         out_specs=shard.q_decode,
-                         check_rep=False)(q, k_cache, v_cache, kpos, pos)
+        return jax.shard_map(call, mesh=shard.mesh, in_specs=base,
+                             out_specs=shard.q_decode,
+                             check_vma=False)(q, k_cache, v_cache, kpos, pos)
     # rank-4 scales (B, L, Hkv, 1) shard exactly like the caches
-    return shard_map(call, mesh=shard.mesh,
-                     in_specs=base + (shard.kv, shard.kv),
-                     out_specs=shard.q_decode,
-                     check_rep=False)(q, k_cache, v_cache, kpos, pos,
-                                      ks, vs)
+    return jax.shard_map(call, mesh=shard.mesh,
+                         in_specs=base + (shard.kv, shard.kv),
+                         out_specs=shard.q_decode,
+                         check_vma=False)(q, k_cache, v_cache, kpos, pos,
+                                          ks, vs)
 
 
 @functools.partial(jax.jit, static_argnames=("shard", "interpret"))
@@ -531,10 +528,7 @@ def _decode_cp_call(q, k_cache, v_cache, kpos, pos, ks, vs, shard,
     axes = shard.seq_axes
 
     def call(q, kc, vc, kp, p, ks=None, vs=None):
-        l_loc = kc.shape[1]
-        bk = min(1024, l_loc)
-        while l_loc % bk:
-            bk //= 2
+        bk = kv_block_rows(*kc.shape[1:], kc.dtype.itemsize)
         acc, m, l = decode_attention_partials(q, kc, vc, kp, p, block_k=bk,
                                               interpret=interpret,
                                               k_scale=ks, v_scale=vs)
@@ -549,15 +543,15 @@ def _decode_cp_call(q, k_cache, v_cache, kpos, pos, ks, vs, shard,
     base = (shard.q_decode, shard.kv, shard.kv, shard.kpos,
             shard.pos_decode)
     if ks is None:
-        return shard_map(call, mesh=shard.mesh, in_specs=base,
-                         out_specs=shard.q_decode,
-                         check_rep=False)(q, k_cache, v_cache, kpos, pos)
+        return jax.shard_map(call, mesh=shard.mesh, in_specs=base,
+                             out_specs=shard.q_decode,
+                             check_vma=False)(q, k_cache, v_cache, kpos, pos)
     # the seq-sharded cache slice carries its seq-sharded scale slice
-    return shard_map(call, mesh=shard.mesh,
-                     in_specs=base + (shard.kv, shard.kv),
-                     out_specs=shard.q_decode,
-                     check_rep=False)(q, k_cache, v_cache, kpos, pos,
-                                      ks, vs)
+    return jax.shard_map(call, mesh=shard.mesh,
+                         in_specs=base + (shard.kv, shard.kv),
+                         out_specs=shard.q_decode,
+                         check_vma=False)(q, k_cache, v_cache, kpos, pos,
+                                          ks, vs)
 
 
 def _decode_dense(q, k_cache, v_cache, kpos, pos):
@@ -972,9 +966,9 @@ def _rmsnorm_fwd_call(x2, scale, eps, shard, interpret, save_residuals):
         return call(x2, scale)
     from jax.sharding import PartitionSpec as P
     out_specs = (shard.rows, shard.rstd) if save_residuals else shard.rows
-    return shard_map(call, mesh=shard.mesh,
-                     in_specs=(shard.rows, P(None)),
-                     out_specs=out_specs, check_rep=False)(x2, scale)
+    return jax.shard_map(call, mesh=shard.mesh,
+                         in_specs=(shard.rows, P(None)),
+                         out_specs=out_specs, check_vma=False)(x2, scale)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -1000,11 +994,11 @@ def _rmsnorm_pallas_bwd(eps, shard, interpret, res, dy):
         dx, dscale = call(x2, scale, rstd, dy)
     else:
         from jax.sharding import PartitionSpec as P
-        dx, dscale = shard_map(call, mesh=shard.mesh,
-                               in_specs=(shard.rows, P(None), shard.rstd,
-                                         shard.rows),
-                               out_specs=(shard.rows, P(None)),
-                               check_rep=False)(x2, scale, rstd, dy)
+        dx, dscale = jax.shard_map(call, mesh=shard.mesh,
+                                   in_specs=(shard.rows, P(None), shard.rstd,
+                                             shard.rows),
+                                   out_specs=(shard.rows, P(None)),
+                                   check_vma=False)(x2, scale, rstd, dy)
     return dx, dscale.astype(scale.dtype)
 
 

@@ -6,16 +6,21 @@ single MXU matmul with the online-softmax state (m, l, acc) held in VMEM
 scratch across the innermost (arbitrary-order) KV grid dimension.  Block
 shapes are MXU-aligned (multiples of 128 on the contracting/lane dims).
 
-Grid: (batch, q_heads, n_q_blocks, n_k_blocks), KV innermost.
-GQA: the k/v BlockSpec index maps q-head h to kv-head h // group, so
-repeated KV heads are never materialized in HBM or VMEM.
+Grid: (batch, q_heads, n_q_blocks, n_k_blocks), KV innermost.  The
+wrapper moves q, k and v to head-major (B, H, S, D) so every block spans
+the two minor dims Mosaic tiles; the per-row log-sum-exp rides as
+(B, Hq, S, 1).  GQA: the k/v BlockSpec index maps q-head h to kv-head
+h // group, so repeated KV heads are never materialized in HBM or VMEM.
 
 ``flash_attention_append`` decouples the q and kv grid dimensions for
 chunked prefill (Sq != Sk): C/bq query blocks at absolute positions
 ``pos0 + i`` scan ceil(Sk/bk) key blocks covering the cache prefix plus
 the chunk, with causal/sliding-window masks on absolute positions from a
 runtime per-row ``kpos`` map (the decode kernel's validity convention)
-and the ``tile_live`` skip for provably-dead prefix tiles.
+and the ``tile_live`` skip for provably-dead prefix tiles.  It reads the
+key stream — in serving, the KV cache — in its own (B, Sk, Hkv, D) layout,
+one all-head block of key rows per grid step, and walks the heads in the
+body, so no step transposes or copies the cache.
 """
 from __future__ import annotations
 
@@ -27,10 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels._interpret import default_interpret
-
-NEG = -1e30
+from repro.kernels.decode_attention import NEG, VMEM_LIMIT
 
 
 def tile_mask(iq, ik, block_q: int, block_k: int, causal: bool,
@@ -100,20 +103,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]                      # (bq, D)
-    k = k_ref[0, :, 0, :]                # (bk, D)
-    v = v_ref[0, :, 0, :]                # (bk, D)
+    k = k_ref[0, 0]                      # (bk, D)
+    v = v_ref[0, 0]                      # (bk, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
     mask = tile_mask(iq, ik, block_q, block_k, causal, window)
     s = jnp.where(mask, s, NEG)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                  # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + \
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + \
         jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -121,7 +124,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ik == n_k - 1)
     def _finish():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         if lse_ref is not None:
             lse_ref[0, 0] = m_ref[...] + jnp.log(l_safe)
 
@@ -154,9 +157,9 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
                               lambda b_, h, iq, ik: (b_, h, iq, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, hq, s, d), q.dtype)]
     if save_residuals:
-        out_specs.append(pl.BlockSpec((1, 1, bq),
-                                      lambda b_, h, iq, ik: (b_, h, iq)))
-        out_shape.append(jax.ShapeDtypeStruct((b, hq, s), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, bq, 1),
+                                      lambda b_, h, iq, ik: (b_, h, iq, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32))
     else:
         def kern(q_ref, k_ref, v_ref, o_ref, *scratch, _full=kern):
             _full(q_ref, k_ref, v_ref, o_ref, None, *scratch)
@@ -166,26 +169,26 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h, iq, ik, g=g: (b_, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h, iq, ik, g=g: (b_, h // g, ik, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(jnp.moveaxis(q, 1, 2), k, v)
+    )(jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2))
     o = out[0].swapaxes(1, 2)
     if save_residuals:
-        return o, out[1]
+        return o, out[1][..., 0]
     return o
 
 
@@ -195,12 +198,13 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
 
 def _append_kernel(q_ref, k_ref, v_ref, kpos_ref, *refs, pos0: int,
                    window: Optional[int], block_q: int, block_k: int,
-                   n_k: int, scale: float, kpos_linear: bool, quant: bool):
+                   n_k: int, group: int, n_kv_heads: int, scale: float,
+                   kpos_linear: bool, quant: bool):
     if quant:
         ks_ref, vs_ref, *refs = refs
     o_ref, m_ref, l_ref, acc_ref = refs
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -216,39 +220,49 @@ def _append_kernel(q_ref, k_ref, v_ref, kpos_ref, *refs, pos0: int,
                      q_offset=pos0) if kpos_linear else None
 
     def _body():
-        q = q_ref[0, 0]                      # (bq, D)
-        k = k_ref[0, :, 0, :]                # (bk, D)
-        v = v_ref[0, :, 0, :]                # (bk, D)
-        if quant:
-            # dequant in VMEM: the int8 key stream carries per-(row, head)
-            # f32 scales ((bk, 1) blocks) that broadcast over the lane dim
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0, :]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
         # causal/window on ABSOLUTE positions: q row r sits at
         # pos0 + iq*bq + r; the key positions come from the runtime kpos
         # row map (-1 = unwritten slot), same validity the decode kernel
         # applies per cache row
         qpos = pos0 + iq * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
-        kp = kpos_ref[0, :]                  # (bk,)
-        mask = (kp[None, :] >= 0) & (kp[None, :] <= qpos)
+        kp = kpos_ref[0]                     # (1, bk)
+        mask = (kp >= 0) & (kp <= qpos)
         if window is not None:
-            mask &= kp[None, :] > qpos - window
-        s = jnp.where(mask, s, NEG)
+            mask &= kp > qpos - window
+        if quant:
+            # the int8 key stream's per-(row, head) scales arrive as a
+            # (bk, Hkv) block, transposed once so each head's scales are a
+            # (1, bk) lane row that scales its scores and probabilities
+            ks = ks_ref[0].T                 # (Hkv, bk)
+            vs = vs_ref[0].T
+        for hk in range(n_kv_heads):
+            k = k_ref[0, :, hk, :]           # (bk, D)
+            v = v_ref[0, :, hk, :]           # (bk, D)
+            if quant:
+                k = k.astype(jnp.float32)
+                v = v.astype(jnp.float32)
+            for h in range(hk * group, (hk + 1) * group):
+                q = q_ref[0, h]              # (bq, D)
+                if quant:
+                    q = q.astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quant:
+                    s = s * ks[hk:hk + 1]    # q.(k8 * ks) == (q.k8) * ks
+                s = jnp.where(mask, s, NEG)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + \
-            jax.lax.dot_general(p.astype(v.dtype), v,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+                m_prev = m_ref[h]            # (bq, 1)
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+                pv = p * vs[hk:hk + 1] if quant else p
+                acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                    pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
 
     if live is None:
         _body()
@@ -258,7 +272,7 @@ def _append_kernel(q_ref, k_ref, v_ref, kpos_ref, *refs, pos0: int,
     @pl.when(ik == n_k - 1)
     def _finish():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def flash_attention_append(q, k, v, kpos, *, pos0: int,
@@ -294,41 +308,36 @@ def flash_attention_append(q, k, v, kpos, *, pos0: int,
 
     kern = functools.partial(
         _append_kernel, pos0=pos0, window=window, block_q=bq, block_k=bk,
-        n_k=n_k, scale=d ** -0.5, kpos_linear=kpos_linear, quant=quant)
+        n_k=n_k, group=g, n_kv_heads=hkv, scale=d ** -0.5,
+        kpos_linear=kpos_linear, quant=quant)
+    rows = pl.BlockSpec((1, bk, hkv, d), lambda b_, iq, ik: (b_, ik, 0, 0))
+    heads = pl.BlockSpec((1, hq, bq, d), lambda b_, iq, ik: (b_, 0, iq, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, bq, d),
-                     lambda b_, h, iq, ik: (b_, h, iq, 0)),
-        pl.BlockSpec((1, bk, 1, d),
-                     lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
-        pl.BlockSpec((1, bk, 1, d),
-                     lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
-        pl.BlockSpec((1, bk), lambda b_, h, iq, ik: (b_, ik)),
+        heads,
+        rows,
+        rows,
+        pl.BlockSpec((1, 1, bk), lambda b_, iq, ik: (b_, 0, ik)),
     ]
-    operands = [jnp.moveaxis(q, 1, 2), k, v, kpos.astype(jnp.int32)]
+    operands = [jnp.moveaxis(q, 1, 2), k, v,
+                kpos.astype(jnp.int32)[:, None, :]]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, bk, 1, 1),
-                         lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, 1),
-                         lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
-        ]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # scales (B, Sk, Hkv, 1) with the unit lane dim dropped (free)
+        scales = pl.BlockSpec((1, bk, hkv), lambda b_, iq, ik: (b_, ik, 0))
+        in_specs += [scales, scales]
+        operands += [k_scale.astype(jnp.float32)[..., 0],
+                     v_scale.astype(jnp.float32)[..., 0]]
     out = pl.pallas_call(
         kern,
-        grid=(b, hq, n_q, n_k),
+        grid=(b, n_q, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b_, h, iq, ik: (b_, h, iq, 0)),
+        out_specs=heads,
         out_shape=jax.ShapeDtypeStruct((b, hq, c, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((hq, bq, 1), jnp.float32),
+                        pltpu.VMEM((hq, bq, 1), jnp.float32),
+                        pltpu.VMEM((hq, bq, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
     return out.swapaxes(1, 2)

@@ -24,8 +24,10 @@ sliding window) are *skipped*: the matmul body is predicated on
 exactly zero.  Accumulator init/flush stay unconditional — they key off
 grid position, not mask content.
 
-GQA uses the forward's ``h // group`` BlockSpec index-map trick for the
-K/V *reads* (repeated KV heads never touch HBM); the dk/dv *writes* are
+K/V move to head-major (B, Hkv, S, D) and lse/delta ride as (B, Hq, S, 1),
+so every block spans the two minor dims Mosaic tiles.  GQA uses the
+forward's ``h // group`` BlockSpec index-map trick for the K/V *reads*
+(repeated KV heads never touch HBM); the dk/dv *writes* are
 per-query-head (a block revisited by every head of a group across outer
 grid steps cannot accumulate safely), and the cheap ``(Hkv, G)`` group-sum
 happens in jnp outside the kernel — identical to the blockwise-jnp path.
@@ -40,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels._interpret import default_interpret
 from repro.kernels.flash_attention import NEG, tile_live, tile_mask
 
@@ -52,7 +53,7 @@ def _recompute_p(q, k, lse, iq, ik, *, block_q, block_k, causal, window,
                             preferred_element_type=jnp.float32) * scale
     mask = tile_mask(iq, ik, block_q, block_k, causal, window)
     s = jnp.where(mask, s, NEG)
-    return jnp.exp(s - lse[:, None])
+    return jnp.exp(s - lse)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
@@ -67,21 +68,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
         # fused delta: rowsum(do * o) over the q tile, once per q block
         delta = jnp.sum(do_ref[0, 0].astype(jnp.float32) *
-                        o_ref[0, 0].astype(jnp.float32), axis=1)
+                        o_ref[0, 0].astype(jnp.float32), axis=1,
+                        keepdims=True)
         delta_acc_ref[...] = delta
         delta_ref[0, 0] = delta
 
     def _compute():
         q = q_ref[0, 0]                  # (bq, D)
-        k = k_ref[0, :, 0, :]            # (bk, D)
-        v = v_ref[0, :, 0, :]            # (bk, D)
+        k = k_ref[0, 0]                  # (bk, D)
+        v = v_ref[0, 0]                  # (bk, D)
         do = do_ref[0, 0]                # (bq, D)
         p = _recompute_p(q, k, lse_ref[0, 0], iq, ik, block_q=block_q,
                          block_k=block_k, causal=causal, window=window,
                          scale=scale)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_acc_ref[...][:, None]) * scale
+        ds = p * (dp - delta_acc_ref[...]) * scale
         dq_acc_ref[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -111,8 +113,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _compute():
         q = q_ref[0, 0]                  # (bq, D)
-        k = k_ref[0, :, 0, :]            # (bk, D)
-        v = v_ref[0, :, 0, :]            # (bk, D)
+        k = k_ref[0, 0]                  # (bk, D)
+        v = v_ref[0, 0]                  # (bk, D)
         do = do_ref[0, 0]                # (bq, D)
         p = _recompute_p(q, k, lse_ref[0, 0], iq, ik, block_q=block_q,
                          block_k=block_k, causal=causal, window=window,
@@ -122,7 +124,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0]) * scale
         dk_acc_ref[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -162,6 +164,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
     qh = jnp.moveaxis(q, 1, 2)                      # (B,Hq,S,D)
     doh = jnp.moveaxis(do, 1, 2)
     oh = jnp.moveaxis(o, 1, 2)
+    kh = jnp.moveaxis(k, 1, 2)                      # (B,Hkv,S,D)
+    vh = jnp.moveaxis(v, 1, 2)
+    lse = lse[..., None]                            # (B,Hq,S,1)
 
     # --- dq (+ fused delta): grid (B, Hq, n_q, n_k), KV innermost ----------
     dq, delta = pl.pallas_call(
@@ -171,32 +176,34 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h, iq, ik, g=g: (b_, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h, iq, ik, g=g: (b_, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h, iq, ik, g=g: (b_, h // g, ik, 0)),
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, iq, ik: (b_, h, iq)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, iq, ik: (b_, h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, iq, ik: (b_, h, iq)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, iq, ik: (b_, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((bq,), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+                        pltpu.VMEM((bq, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qh, k, v, doh, oh, lse)
+    )(qh, kh, vh, doh, oh, lse)
     dq = dq.swapaxes(1, 2)
 
     # --- dk/dv: grid (B, Hq, n_k, n_q), Q innermost -------------------------
@@ -207,14 +214,16 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, ik, iq: (b_, h, iq, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h, ik, iq, g=g: (b_, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h, ik, iq, g=g: (b_, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h, ik, iq, g=g: (b_, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h, ik, iq, g=g: (b_, h // g, ik, 0)),
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, ik, iq: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, ik, iq: (b_, h, iq)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, ik, iq: (b_, h, iq)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, ik, iq: (b_, h, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, ik, iq: (b_, h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d),
@@ -228,11 +237,11 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qh, k, v, doh, lse, delta)
+    )(qh, kh, vh, doh, lse, delta)
 
     # group-sum the per-query-head dk/dv back to kv heads: (B,Hq,S,D) ->
     # (B,S,Hkv,D).  One small reduce; the kernels stay write-disjoint.

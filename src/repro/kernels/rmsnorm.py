@@ -37,7 +37,7 @@ def _kernel(x_ref, scale_ref, o_ref, rstd_ref, *, eps: float, d_real: int):
     y = x * rstd * scale_ref[...].astype(jnp.float32)
     o_ref[...] = y.astype(o_ref.dtype)
     if rstd_ref is not None:
-        rstd_ref[...] = rstd[:, 0]
+        rstd_ref[...] = rstd
 
 
 def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
@@ -55,8 +55,10 @@ def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
     out_specs = [pl.BlockSpec((br, d), lambda i: (i, 0))]
     out_shape = [jax.ShapeDtypeStruct((rows, d), x.dtype)]
     if save_residuals:
-        out_specs.append(pl.BlockSpec((br,), lambda i: (i,)))
-        out_shape.append(jax.ShapeDtypeStruct((rows,), jnp.float32))
+        # rstd rides as a (rows, 1) column: Mosaic tiles the last two block
+        # dims, and a 1-D (br,) block of a longer array is not tile-aligned
+        out_specs.append(pl.BlockSpec((br, 1), lambda i: (i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((rows, 1), jnp.float32))
     else:
         def kern(x_ref, scale_ref, o_ref, _full=kern):
             _full(x_ref, scale_ref, o_ref, None)
@@ -72,7 +74,7 @@ def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
         interpret=interpret,
     )(x, scale)
     if save_residuals:
-        return out[0], out[1]
+        return out[0], out[1][:, 0]
     return out[0]
 
 
@@ -81,12 +83,12 @@ def _bwd_kernel(x_ref, scale_ref, rstd_ref, dy_ref, dx_ref, dscale_ref, *,
     x = x_ref[...].astype(jnp.float32)           # (br, d)
     dy = dy_ref[...].astype(jnp.float32)
     s = scale_ref[...].astype(jnp.float32)       # (d,)
-    r = rstd_ref[...][:, None]                   # (br, 1)
+    r = rstd_ref[...]                            # (br, 1)
     dys = dy * s[None, :]
     c = jnp.sum(dys * x, axis=-1, keepdims=True) / d_real
     dx = (dys - x * (r * r) * c) * r
     dx_ref[...] = dx.astype(dx_ref.dtype)
-    dscale_ref[...] = jnp.sum(dy * x * r, axis=0)[None, :]
+    dscale_ref[...] = jnp.sum(dy * x * r, axis=0)[None, None, :]
 
 
 def rmsnorm_bwd(x, scale, rstd, dy, *, block_rows: int = 256,
@@ -107,17 +109,17 @@ def rmsnorm_bwd(x, scale, rstd, dy, *, block_rows: int = 256,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((br,), lambda i: (i,)),
+            pl.BlockSpec((br, 1), lambda i: (i, 0)),
             pl.BlockSpec((br, d), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, d), x.dtype),
-            jax.ShapeDtypeStruct((n_blocks, d), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, d), jnp.float32),
         ],
         interpret=interpret,
-    )(x, scale, rstd, dy)
-    return dx, dscale_part.sum(0)
+    )(x, scale, rstd[:, None], dy)
+    return dx, dscale_part.sum((0, 1))

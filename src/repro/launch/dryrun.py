@@ -23,7 +23,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_config, ARCH_IDS, ALIASES
 from repro.core import llm_a3c
 from repro.distributed import ctx, sharding
@@ -80,7 +79,7 @@ def lower_case(arch: str, shape_id: str, *, multi_pod: bool = False,
     # keys off the mesh's device platform (the lowering target), and the
     # dispatcher shard_maps the Pallas kernels over (data, heads)
     dispatch.clear_decision_log()
-    with compat.set_mesh(mesh), ctx.use_mesh(mesh), \
+    with jax.set_mesh(mesh), ctx.use_mesh(mesh), \
             ctx.sharding_rules(rules):
         if kind == "train" and mode == "delayed":
             # T3: paper-faithful pod-scale asynchrony — each pod updates a
@@ -195,7 +194,7 @@ def lower_case(arch: str, shape_id: str, *, multi_pod: bool = False,
         compiled = lowered.compile()
         t_compile = time.time() - t0
 
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mem = _mem_summary(compiled)
     hlo_text = compiled.as_text()
     weighted = hlo_analysis.weighted_totals(hlo_text)

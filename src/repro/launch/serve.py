@@ -2229,7 +2229,9 @@ def _range(s: str):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's reduced smoke config instead of "
+                    "its published widths")
     ap.add_argument("--mode", choices=("engine", "lockstep"),
                     default="engine")
     ap.add_argument("--slots", "--batch", dest="slots", type=int, default=4)
@@ -2334,13 +2336,14 @@ def main():
 
     import jax
 
-    from repro import compat
     from repro.configs import get_config
     from repro.distributed import ctx, sharding
     from repro.kernels import dispatch
-    from repro.launch import hlo_analysis
+    from repro.launch import compile_cache, hlo_analysis
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -2349,7 +2352,11 @@ def main():
             f"{cfg.name}: the serve engine drives token-in/token-out LMs; "
             "VLM embeds / encoder-decoder memories have no request-queue "
             "source here (the decode dry-run still lowers those shapes)")
-    params = M.init_params(cfg, jax.random.key(args.seed))
+    # serving holds the compute-dtype weights only: every step casts to
+    # them anyway, and f32 masters would double the weights' HBM.  Jitted,
+    # so the f32 draws never sit in HBM next to the bf16 result.
+    params = jax.jit(lambda k: M.cast_params(cfg, M.init_params(cfg, k)))(
+        jax.random.key(args.seed))
     cache_len = args.cache_len or (
         args.prompt_range[1] + args.gen_range[1])
     trace = gen_trace(args.requests, vocab=cfg.vocab_size,
@@ -2374,9 +2381,9 @@ def main():
     with contextlib.ExitStack() as stack:
         if args.decode_cp:
             n_dev = len(jax.devices())
-            mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+            mesh = make_mesh((1, n_dev), ("data", "model"))
             rules = sharding.decode_rules(cfg, mesh, batch_size=args.slots)
-            stack.enter_context(compat.set_mesh(mesh))
+            stack.enter_context(jax.set_mesh(mesh))
             stack.enter_context(ctx.use_mesh(mesh))
             stack.enter_context(ctx.sharding_rules(rules))
             n_shards = rules["decode_cp"]["n_shards"]

@@ -91,8 +91,10 @@ def run_llm(args) -> dict:
     mesh_ctx = contextlib.nullcontext()
     if n_dev > 1 and args.batch % n_dev == 0:
         mesh_ctx = ctx.use_mesh(make_debug_mesh(data=n_dev, model=1))
+    # params and optimizer state are rebound every step: donating them lets
+    # the update write in place instead of holding two copies on device
     train_step = jax.jit(llm_a3c.make_train_step(
-        cfg, opt, lr0=args.lr, total_steps=args.steps))
+        cfg, opt, lr0=args.lr, total_steps=args.steps), donate_argnums=(0, 1))
     history = []
     t0 = time.time()
     # dispatch resolves at trace time, so the mesh stays installed for the
@@ -146,6 +148,8 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.mode == "rl":
         run_rl(args)
     else:

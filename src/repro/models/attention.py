@@ -198,7 +198,6 @@ def _update_kv_cache_cp(cache: dict, k, v, slot, cp, ks=None, vs=None
     (``ks``/``vs`` (B, 1, Hkv, 1)); the rank-matched scale leaves take the
     exact same predicated write.  Returns (ck, cv) or (ck, cv, cks, cvs).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import decode_cp_spec
@@ -235,11 +234,11 @@ def _update_kv_cache_cp(cache: dict, k, v, slot, cp, ks=None, vs=None
                 jnp.where(sel, nw[:, 0].astype(od.dtype), od[rows, ls]))
             for nw, od in zip(new, old))
 
-    return shard_map(write, mesh=mesh,
-                     in_specs=(P(spec.batch),) + (spec.new_kv,) * n +
-                              (spec.kv,) * n,
-                     out_specs=(spec.kv,) * n,
-                     check_rep=False)(slot, *new_rows, *leaves)
+    return jax.shard_map(write, mesh=mesh,
+                         in_specs=(P(spec.batch),) + (spec.new_kv,) * n +
+                         (spec.kv,) * n,
+                         out_specs=(spec.kv,) * n,
+                         check_vma=False)(slot, *new_rows, *leaves)
 
 
 def attend_decode(params: dict, x: jnp.ndarray, cache: dict, pos: jnp.ndarray,

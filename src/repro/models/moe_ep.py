@@ -71,8 +71,6 @@ def moe_apply_ep(p: dict, x: jnp.ndarray, *, top_k: int,
                  mesh, dp_axes: Tuple[str, ...],
                  tp_axis: str = "model") -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Expert-parallel MoE.  x (B, S, d); S must divide by |tp_axis|."""
-    from jax.experimental.shard_map import shard_map
-
     b, s, d = x.shape
     e = p["router"].shape[1]
     tp = mesh.shape[tp_axis]
@@ -107,7 +105,7 @@ def moe_apply_ep(p: dict, x: jnp.ndarray, *, top_k: int,
 
     dp_spec = dp_axes if (dp_axes and b % dp == 0) else None
     x_spec = P(dp_spec, tp_axis, None)
-    out = shard_map(
+    out = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None),                 # router replicated
                   P(tp_axis, None, None),        # experts on model axis
@@ -115,6 +113,6 @@ def moe_apply_ep(p: dict, x: jnp.ndarray, *, top_k: int,
                   P(tp_axis, None, None),
                   x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
     return out

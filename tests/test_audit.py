@@ -196,6 +196,7 @@ def test_kernel_checker_flags_bad_kernel():
     assert "out of bounds" in msgs, msgs
     assert "write race" in msgs, msgs
     assert "exceeds budget" in msgs, msgs
+    assert "breaks the TPU tiling" in msgs, msgs
 
 
 def test_kernel_checker_budget_is_configurable():

@@ -14,13 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import get_config
 from repro.distributed import ctx, sharding
 from repro.kernels import dispatch
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
-MESH = jax.make_mesh((1, 1), ("data", "model"))
+MESH = make_mesh((1, 1), ("data", "model"))
 MULTI = len(jax.devices()) >= 2
 
 
@@ -38,7 +38,7 @@ def test_decode_cp_matches_dense(arch):
     for t in range(s):
         tb = {"tokens": tokens[:, t:t + 1]}
         o1, c1 = M.decode_step(cfg, params, c1, tb, jnp.asarray(t))
-        with compat.set_mesh(MESH), ctx.sharding_rules(rules):
+        with jax.set_mesh(MESH), ctx.sharding_rules(rules):
             o2, c2 = M.decode_step(cfg, params, c2, tb, jnp.asarray(t))
         np.testing.assert_allclose(np.asarray(o1["logits"]),
                                    np.asarray(o2["logits"]),
@@ -56,7 +56,7 @@ def test_decode_cp_multidevice_resolves_pallas_cp():
     b, cache_len, steps = 2, 256, 4
     tokens = jax.random.randint(jax.random.key(1), (b, steps), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     c1 = M.init_cache(cfg, b, cache_len, dtype=jnp.float32)
     c2 = M.init_cache(cfg, b, cache_len, dtype=jnp.float32)
     rules = sharding.decode_rules(cfg, mesh, batch_size=b)
@@ -64,7 +64,7 @@ def test_decode_cp_multidevice_resolves_pallas_cp():
     for t in range(steps):
         tb = {"tokens": tokens[:, t:t + 1]}
         o1, c1 = M.decode_step(cfg, params, c1, tb, jnp.asarray(t))
-        with compat.set_mesh(mesh), ctx.sharding_rules(rules):
+        with jax.set_mesh(mesh), ctx.sharding_rules(rules):
             dispatch.clear_decision_log()
             o2, c2 = M.decode_step(cfg, params, c2, tb, jnp.asarray(t))
             d = dispatch.last_decision("decode_attention")
@@ -91,7 +91,7 @@ def test_decode_cp_ring_cache():
     cache = M.init_cache(cfg, b, s, dtype=jnp.float32)
     rules = sharding.decode_rules(cfg, MESH, batch_size=b)
     outs = []
-    with compat.set_mesh(MESH), ctx.sharding_rules(rules):
+    with jax.set_mesh(MESH), ctx.sharding_rules(rules):
         for t in range(s):
             out, cache = M.decode_step(cfg, params, cache,
                                        {"tokens": tokens[:, t:t + 1]},

@@ -15,6 +15,7 @@ import pytest
 
 from repro.distributed import ctx
 from repro.kernels import dispatch, ref
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(7)
 MULTI = len(jax.devices()) >= 2
@@ -86,7 +87,7 @@ def test_lockstep_is_thin_wrapper():
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=2)")
 def test_perslot_shard_map_parity():
     """(batch, heads) shard_map arm with ragged pos, batch on 'data'."""
-    mesh = jax.make_mesh((2, 1), ("data", "model"))
+    mesh = make_mesh((2, 1), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     b, length, hq, hkv, d = 4, 512, 4, 2, 64
     q = jax.random.normal(ks[0], (b, hq, d))
@@ -110,7 +111,7 @@ def test_perslot_pallas_cp_parity():
     """Seq-sharded cache: the pallas_cp combine with ragged per-slot pos —
     a freshly-admitted row whose whole second shard is masked must coexist
     with a deep row that reads both shards."""
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     b, length, hq, hkv, d = 2, 512, 8, 2, 64     # GQA g=4
     q = jax.random.normal(ks[0], (b, hq, d))

@@ -15,6 +15,7 @@ from repro.distributed import ctx
 from repro.kernels import dispatch, ref
 from repro.models import attention as attn
 from repro.models.flash_jnp import flash_attention_jnp
+from repro.launch.mesh import make_mesh
 
 MULTI = len(jax.devices()) >= 2
 KEY = jax.random.key(7)
@@ -100,7 +101,7 @@ def test_attend_train_auto_lowers_shard_map_pallas(mesh_shape):
         return attn.attend_train(params, x, None, None, cfg,
                                  use_rope=False)
 
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     jitted = jax.jit(fn)
     with ctx.use_mesh(mesh):
         dispatch.clear_decision_log()
@@ -122,7 +123,7 @@ def test_attend_train_auto_lowers_shard_map_pallas(mesh_shape):
 
 @pytest.mark.skipif(not MULTI, reason="needs >= 2 devices")
 def test_auto_mesh_indivisible_heads_falls_back():
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     q, k, v, _ = _qkv(1, 256, 3, 3, 64)    # 3 heads on a 2-way model axis
     with ctx.use_mesh(mesh):
         dispatch.clear_decision_log()
@@ -140,7 +141,7 @@ def test_auto_mesh_indivisible_heads_falls_back():
 # ---------------------------------------------------------------------------
 
 def _parity_case(mesh_shape, b, s, hq, hkv, d, window, causal, dtype):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     q, k, v, do = _qkv(b, s, hq, hkv, d, dtype)
 
     def loss_sharded(q, k, v):
@@ -202,7 +203,7 @@ def test_sharded_parity_sweep(mesh_shape, b, s, hq, hkv, d, window, causal,
 
 @pytest.mark.skipif(not MULTI, reason="needs >= 2 devices")
 def test_sharded_decode_parity():
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     b, length, hq, hkv, d = 2, 512, 4, 2, 64
     q = jax.random.normal(ks[0], (b, hq, d))
@@ -226,7 +227,7 @@ def test_decode_shard_map_misaligned_is_logged_fallback():
     """Explicit backend="pallas_shard_map": non-divisible heads / misaligned
     cache length fall back to jnp with a logged reason instead of raising
     (serving batch/head counts vary per request)."""
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     pos = jnp.asarray(100, jnp.int32)
     with ctx.use_mesh(mesh):
@@ -274,7 +275,7 @@ def test_decode_cp_pallas_parity():
     """Seq-sharded cache + GQA + ragged kpos: the pallas_cp combine must
     match the jnp oracle to <= 1e-5 and the decision must record it — the
     'context-parallel rules own the cache -> jnp' fallback is gone."""
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     b, length, hq, hkv, d = 2, 512, 8, 2, 64     # GQA g=4
     q = jax.random.normal(ks[0], (b, hq, d))
@@ -300,7 +301,7 @@ def test_decode_cp_pallas_parity():
 def test_decode_cp_one_shard_fully_masked():
     """pos inside the first shard's slice: the second shard is all-masked
     (m = -inf) and must vanish in the combine, not poison it."""
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     b, length = 1, 256
     q = jax.random.normal(ks[0], (b, 4, 64))
@@ -330,7 +331,7 @@ def test_decode_cp_one_shard_fully_masked():
         (4, 512, 8, 4, 128, 400, ("data",)),   # wide head_dim
     ])
 def test_decode_cp_parity_sweep(b, length, hq, hkv, d, pos, dp_axes):
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, hq, d))
     kc = jax.random.normal(ks[1], (b, length, hkv, d))
@@ -355,7 +356,7 @@ def test_decode_cp_fallback_reason_sweep():
     """Where the old code had a blanket 'decode_cp -> jnp' branch, the
     resolver now falls back only when the layout cannot serve the call —
     each with a logged reason (and numeric parity through the jnp path)."""
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     ks = jax.random.split(KEY, 3)
     pos = jnp.asarray(100, jnp.int32)
     q = jax.random.normal(ks[0], (2, 4, 64))
@@ -411,7 +412,7 @@ def test_mesh_switch_relowers_with_new_resolution():
     kpos = jnp.where(jnp.arange(length) <= pos, jnp.arange(length), -1)
     jitted = jax.jit(lambda *a: dispatch.decode_attention(*a))
 
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     with ctx.use_mesh(mesh):
         dispatch.clear_decision_log()
         out_mesh = jitted(q, kc, vc, kpos, pos)
@@ -448,7 +449,7 @@ def test_mesh_reentry_hits_trace_cache():
 
     fn(q, k, v)
     assert len(traces) == 1
-    mesh = jax.make_mesh((len(jax.devices()), 1)
+    mesh = make_mesh((len(jax.devices()), 1)
                          if MULTI else (1, 1), ("data", "model"))
     with ctx.use_mesh(mesh):
         fn(q, k, v)
@@ -470,7 +471,7 @@ def test_mesh_reentry_hits_trace_cache():
 def test_rmsnorm_auto_mesh_shard_map_parity(mesh_shape):
     """Under a mesh rmsnorm now shard_maps over row blocks (scale
     replicated, dscale psum'd) instead of silently downgrading to jnp."""
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     x = jax.random.normal(KEY, (4, 8, 128))
     scale = jnp.ones((128,)) * 1.5
 
@@ -499,7 +500,7 @@ def test_rmsnorm_seq_parallel_residual_explicit_fallback():
     reason (rows are sharded over 'model'; a row-block shard_map would
     re-gather the residual stream)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     x = jax.random.normal(KEY, (4, 8, 128))
     scale = jnp.ones((128,))
     rules = {"residual": NamedSharding(mesh, P(None, "model", None))}

@@ -19,6 +19,7 @@ from repro.configs import get_config
 from repro.distributed import ctx
 from repro.kernels import dispatch, ref
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(11)
 
@@ -168,7 +169,7 @@ def test_append_dispatch_shard_map_1dev_mesh():
     sk = pos0 + c
     q, k, v = _qkv(b, c, sk, hq, hkv, d)
     kpos = _linear_kpos(sk, pos0, c)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with ctx.use_mesh(mesh):
         out = dispatch.flash_attention_append(
             q, k, v, kpos, pos0=pos0, kpos_linear=True,
@@ -188,7 +189,7 @@ def test_append_dispatch_auto_mesh_2dev():
     sk = pos0 + c
     q, k, v = _qkv(b, c, sk, hq, hkv, d)
     kpos = _linear_kpos(sk, pos0, c)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     with ctx.use_mesh(mesh):
         dispatch.clear_decision_log()
         out = dispatch.flash_attention_append(q, k, v, kpos, pos0=pos0,

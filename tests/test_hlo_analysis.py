@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.launch import hlo_analysis as H
 
 
@@ -22,14 +21,14 @@ def test_xla_cost_analysis_undercounts_scan():
     x = jnp.zeros((4, 128))
     w = jnp.zeros((8, 128, 128))
     c_scan = jax.jit(f).lower(x, w).compile()
-    flops_scan = compat.cost_analysis(c_scan).get("flops", 0)
+    flops_scan = c_scan.cost_analysis().get("flops", 0)
 
     def unrolled(x, w):
         for i in range(8):
             x = jnp.tanh(x @ w[i])
         return x
     c_unr = jax.jit(unrolled).lower(x, w).compile()
-    flops_unr = compat.cost_analysis(c_unr).get("flops", 0)
+    flops_unr = c_unr.cost_analysis().get("flops", 0)
     # the documented defect: scan counted once vs 8x
     assert flops_unr > 6 * flops_scan
 

@@ -152,6 +152,18 @@ def test_decode_attention_ring_cache():
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("itemsize", [1, 2, 4])
+def test_kv_block_rows_tile_aligned(itemsize):
+    """Every 128-multiple cache length gets a K/V block that divides it,
+    is a multiple of 128 (the kpos block's lane dim) and stays within the
+    2 MiB block budget once the lanes pad D=64 to 128."""
+    from repro.kernels.decode_attention import kv_block_rows
+    for length in range(128, 4096 + 1, 128):
+        bk = kv_block_rows(length, 32, 64, itemsize)
+        assert length % bk == 0 and bk % 128 == 0, (length, bk)
+        assert bk == 128 or bk * 32 * 128 * itemsize <= 2 << 20
+
+
 @pytest.mark.parametrize("shape", [(64,), (1000,), (128, 128), (7, 321),
                                    (3, 5, 7)])
 @pytest.mark.parametrize("lr", [1e-4, 1e-2])

@@ -18,6 +18,7 @@ from repro.kernels import dispatch, kv_quant, ref
 from repro.launch import serve as serve_mod
 from repro.launch import traffic
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(11)
 MULTI = len(jax.devices()) >= 2
@@ -162,7 +163,7 @@ def test_decode_quant_shard_map_and_cp():
     kpos = jnp.broadcast_to(jnp.arange(k.shape[1]), k.shape[:2])
     pos = jnp.asarray([200, 131])
     want = ref.decode_attention_quant_ref(q, k8, v8, ks, vs, kpos, pos)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     with ctx.use_mesh(mesh):
         dispatch.clear_decision_log()
         got = dispatch.decode_attention(q, k8, v8, kpos, pos,

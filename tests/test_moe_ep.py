@@ -4,11 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.models import moe as moe_mod
 from repro.models import moe_ep
+from repro.launch.mesh import make_mesh
 
-MESH = jax.make_mesh((1, 1), ("data", "model"))
+MESH = make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("top_k,cf", [(1, 1.25), (2, 1.25), (2, 4.0)])
@@ -53,6 +53,6 @@ def test_ep_activated_by_rules_in_train_step():
     plain, _ = llm_a3c.a3c_token_loss(cfg, params, batch)
     rules = sharding.activation_rules(MESH, batch_size=b, cfg=cfg)
     assert "moe_ep" in rules
-    with compat.set_mesh(MESH), ctx.sharding_rules(rules):
+    with jax.set_mesh(MESH), ctx.sharding_rules(rules):
         ep, _ = llm_a3c.a3c_token_loss(cfg, params, batch)
     np.testing.assert_allclose(float(plain), float(ep), rtol=1e-5)

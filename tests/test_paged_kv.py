@@ -21,6 +21,7 @@ from repro.launch import serve as serve_mod
 from repro.launch import traffic
 from repro.models import attention as attn
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(7)
 PS = 128
@@ -408,7 +409,6 @@ def test_engine_paged_two_device_mesh():
     """Paged engine under the (batch, heads) mesh: greedy tokens must
     match the single-device no-mesh run, and the paged dispatch arms must
     appear in the decision log."""
-    from repro import compat
     from repro.distributed import ctx, sharding
 
     cfg = _cfg()
@@ -420,10 +420,10 @@ def test_engine_paged_two_device_mesh():
     assert base["paged"]
     want = {r.rid: r.tokens for r in ref_trace}
 
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     rules = sharding.decode_rules(cfg, mesh, batch_size=2)
     mesh_trace = _copy_trace(trace)
-    with compat.set_mesh(mesh), ctx.use_mesh(mesh), \
+    with jax.set_mesh(mesh), ctx.use_mesh(mesh), \
             ctx.sharding_rules(rules):
         dispatch.clear_decision_log()
         rec = serve_mod.run_engine(cfg, params, mesh_trace, **kw)

@@ -17,6 +17,7 @@ from repro.configs import get_config
 from repro.core import llm_a3c
 from repro.launch import serve as serve_mod
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 
 def _cfg():
@@ -240,7 +241,6 @@ def test_engine_decode_cp_smoke():
     """Serve-engine smoke on the 2-dev host mesh: mixed-length requests
     with the seq-sharded cache layout must resolve pallas_cp and match the
     unruled sequential reference."""
-    from repro import compat
     from repro.distributed import ctx, sharding
     from repro.kernels import dispatch
 
@@ -250,9 +250,9 @@ def test_engine_decode_cp_smoke():
                                 prompt_range=(4, 16), gen_range=(2, 5),
                                 arrival_rate=0.0, seed=2)
     cache_len = 256                     # 128-aligned per-shard slices
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     rules = sharding.decode_rules(cfg, mesh, batch_size=2)
-    with compat.set_mesh(mesh), ctx.use_mesh(mesh), \
+    with jax.set_mesh(mesh), ctx.use_mesh(mesh), \
             ctx.sharding_rules(rules):
         dispatch.clear_decision_log()
         rec = serve_mod.run_engine(cfg, params, trace, n_slots=2,

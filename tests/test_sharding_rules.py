@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
+from repro.launch.mesh import abstract_mesh
 from repro.configs import get_config
 from repro.distributed import sharding
 from repro.launch import specs as specs_mod

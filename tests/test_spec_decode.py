@@ -14,6 +14,7 @@ import pytest
 from repro.configs import get_config
 from repro.launch import serve as serve_mod
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 
 def _cfg():
@@ -278,7 +279,6 @@ def test_spec_identity_2dev_mesh():
     """Speculative decode on the 2-dev host mesh (model-sharded decode
     layout): verify + commit ride the same sharded cache, tokens match
     the single-host plain run."""
-    from repro import compat
     from repro.distributed import ctx, sharding
 
     cfg = _cfg()
@@ -287,9 +287,9 @@ def test_spec_identity_2dev_mesh():
                         max_new=6, seed=2)
     _, base = _drive(cfg, params, mk(), spec="off", cache_len=256,
                      chunk=8)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     rules = sharding.decode_rules(cfg, mesh, batch_size=2)
-    with compat.set_mesh(mesh), ctx.use_mesh(mesh), \
+    with jax.set_mesh(mesh), ctx.use_mesh(mesh), \
             ctx.sharding_rules(rules):
         _, toks = _drive(cfg, params, mk(), spec="ngram", cache_len=256,
                          chunk=8)

@@ -6,8 +6,12 @@ recording stub (kernel bodies never run) and each representative case is
 driven under ``jax.eval_shape``, so the checks see exactly the grid,
 BlockSpecs, out_shapes and scratch shapes the real lowering would.
 
-Per captured call, three proofs over every grid point:
+Per captured call, four proofs (the first three over every grid point):
 
+  * tiling             the last two dims of every block are divisible by
+                       (8, 128) or equal the array's — the rule Mosaic,
+                       the TPU's Pallas compiler, enforces and interpret
+                       mode never checks (a rank-1 block: 128 or whole).
   * index-map bounds   every BlockSpec index map stays inside
                        ``ceil(dim / block)`` for every grid index — a
                        map that walks off the array reads (or writes)
@@ -126,6 +130,13 @@ def _check_spec(rec: Record, spec, shape, kind: str, i: int,
                            f"{kind}[{i}]: block rank {len(blk)} != array "
                            f"rank {len(shape)} (shape {shape})"))
         return None
+    for d, align in zip(range(-min(2, len(blk)), 0), (8, 128)[-len(blk):]):
+        if blk[d] % align and blk[d] != shape[d]:
+            v.append(Violation(
+                "kernel-check", loc, 0,
+                f"{kind}[{i}]: block {blk} breaks the TPU tiling: dim "
+                f"{len(blk) + d} is {blk[d]}, neither a multiple of {align} "
+                f"nor the array's {shape[d]} (shape {shape})"))
     nblk = tuple(max(1, math.ceil(d / b)) for d, b in zip(shape, blk))
     blocks: Dict[tuple, list] = {}
     for gp in _grid_points(rec.grid):
