@@ -81,8 +81,11 @@ def make_train_step(cfg: ModelConfig, opt, *, gamma: float = 0.99,
             lambda p: a3c_token_loss(cfg, p, batch, gamma=gamma,
                                      beta=beta),
             has_aux=True)(params)
-        updates, opt_state = opt.update(grads, opt_state, lr)
-        params = opt_mod.apply_updates(params, updates)
+        # one scope over the update and its application: XLA fuses the two,
+        # and a fusion carries its root's (the application's) scope
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, lr)
+            params = opt_mod.apply_updates(params, updates)
         return params, opt_state, metrics
 
     return train_step

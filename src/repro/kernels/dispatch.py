@@ -759,13 +759,14 @@ def decode_attention_paged(q, k_pool, v_pool, page_table, pos, *,
         return ref.decode_attention_paged_ref(q, k_pool, v_pool,
                                               page_table, pos,
                                               length=length)
-    k = ref.paged_gather_ref(k_pool, page_table)[:, :length]
-    v = ref.paged_gather_ref(v_pool, page_table)[:, :length]
-    kpos = ref.paged_kpos_ref(page_table, ps)[:, :length]
-    ks = vs = None
-    if quant:
-        ks = ref.paged_gather_ref(k_scale, page_table)[:, :length]
-        vs = ref.paged_gather_ref(v_scale, page_table)[:, :length]
+    with jax.named_scope("paged_gather"):
+        k = ref.paged_gather_ref(k_pool, page_table)[:, :length]
+        v = ref.paged_gather_ref(v_pool, page_table)[:, :length]
+        kpos = ref.paged_kpos_ref(page_table, ps)[:, :length]
+        ks = vs = None
+        if quant:
+            ks = ref.paged_gather_ref(k_scale, page_table)[:, :length]
+            vs = ref.paged_gather_ref(v_scale, page_table)[:, :length]
     o = decode_attention(q, k, v, kpos, pos, k_scale=ks, v_scale=vs,
                          backend=backend)
     inner = last_decision("decode_attention")
@@ -817,23 +818,24 @@ def flash_attention_append_paged(q, k_pool, v_pool, page_table,
         ks_all, vs_all = ks_chunk, vs_chunk
         kpos = jnp.arange(c)
     else:
-        n_pre = -(-pos0 // ps)
-        pt = page_table[:, :n_pre]
-        k_pre = ref.paged_gather_ref(k_pool, pt)[:, :pos0]
-        v_pre = ref.paged_gather_ref(v_pool, pt)[:, :pos0]
-        if not quant:
-            k_pre = k_pre.astype(q.dtype)
-            v_pre = v_pre.astype(q.dtype)
-        kpos_pre = ref.paged_kpos_ref(pt, ps)[:, :pos0]
-        k_all = jnp.concatenate([k_pre, k_chunk], axis=1)
-        v_all = jnp.concatenate([v_pre, v_chunk], axis=1)
-        kpos_chunk = jnp.broadcast_to(pos0 + jnp.arange(c), (b, c))
-        kpos = jnp.concatenate([kpos_pre, kpos_chunk], axis=1)
-        if quant:
-            ks_pre = ref.paged_gather_ref(k_scale, pt)[:, :pos0]
-            vs_pre = ref.paged_gather_ref(v_scale, pt)[:, :pos0]
-            ks_all = jnp.concatenate([ks_pre, ks_chunk], axis=1)
-            vs_all = jnp.concatenate([vs_pre, vs_chunk], axis=1)
+        with jax.named_scope("paged_gather"):
+            n_pre = -(-pos0 // ps)
+            pt = page_table[:, :n_pre]
+            k_pre = ref.paged_gather_ref(k_pool, pt)[:, :pos0]
+            v_pre = ref.paged_gather_ref(v_pool, pt)[:, :pos0]
+            if not quant:
+                k_pre = k_pre.astype(q.dtype)
+                v_pre = v_pre.astype(q.dtype)
+            kpos_pre = ref.paged_kpos_ref(pt, ps)[:, :pos0]
+            k_all = jnp.concatenate([k_pre, k_chunk], axis=1)
+            v_all = jnp.concatenate([v_pre, v_chunk], axis=1)
+            kpos_chunk = jnp.broadcast_to(pos0 + jnp.arange(c), (b, c))
+            kpos = jnp.concatenate([kpos_pre, kpos_chunk], axis=1)
+            if quant:
+                ks_pre = ref.paged_gather_ref(k_scale, pt)[:, :pos0]
+                vs_pre = ref.paged_gather_ref(v_scale, pt)[:, :pos0]
+                ks_all = jnp.concatenate([ks_pre, ks_chunk], axis=1)
+                vs_all = jnp.concatenate([vs_pre, vs_chunk], axis=1)
     o = flash_attention_append(q, k_all, v_all, kpos, pos0=pos0,
                                kpos_linear=True, k_scale=ks_all,
                                v_scale=vs_all, backend=backend)
@@ -924,24 +926,25 @@ def flash_attention_verify_paged(q, k_pool, v_pool, page_table,
     b, kq = q.shape[0], q.shape[1]
     n_pre = -(-length // ps)
     pt = page_table[:, :n_pre]
-    k_pre = ref.paged_gather_ref(k_pool, pt)[:, :length]
-    v_pre = ref.paged_gather_ref(v_pool, pt)[:, :length]
-    if not quant:
-        k_pre = k_pre.astype(q.dtype)
-        v_pre = v_pre.astype(q.dtype)
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    kpos_pre = ref.paged_kpos_ref(pt, ps)[:, :length]
-    kpos_pre = jnp.where(kpos_pre <= pos[:, None] - 1, kpos_pre, -1)
-    kpos_chunk = pos[:, None] + jnp.arange(kq)
-    kpos = jnp.concatenate([kpos_pre, kpos_chunk], axis=1)
-    k_all = jnp.concatenate([k_pre, k_chunk], axis=1)
-    v_all = jnp.concatenate([v_pre, v_chunk], axis=1)
-    ks_all = vs_all = None
-    if quant:
-        ks_pre = ref.paged_gather_ref(k_scale, pt)[:, :length]
-        vs_pre = ref.paged_gather_ref(v_scale, pt)[:, :length]
-        ks_all = jnp.concatenate([ks_pre, ks_chunk], axis=1)
-        vs_all = jnp.concatenate([vs_pre, vs_chunk], axis=1)
+    with jax.named_scope("paged_gather"):
+        k_pre = ref.paged_gather_ref(k_pool, pt)[:, :length]
+        v_pre = ref.paged_gather_ref(v_pool, pt)[:, :length]
+        if not quant:
+            k_pre = k_pre.astype(q.dtype)
+            v_pre = v_pre.astype(q.dtype)
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+        kpos_pre = ref.paged_kpos_ref(pt, ps)[:, :length]
+        kpos_pre = jnp.where(kpos_pre <= pos[:, None] - 1, kpos_pre, -1)
+        kpos_chunk = pos[:, None] + jnp.arange(kq)
+        kpos = jnp.concatenate([kpos_pre, kpos_chunk], axis=1)
+        k_all = jnp.concatenate([k_pre, k_chunk], axis=1)
+        v_all = jnp.concatenate([v_pre, v_chunk], axis=1)
+        ks_all = vs_all = None
+        if quant:
+            ks_pre = ref.paged_gather_ref(k_scale, pt)[:, :length]
+            vs_pre = ref.paged_gather_ref(v_scale, pt)[:, :length]
+            ks_all = jnp.concatenate([ks_pre, ks_chunk], axis=1)
+            vs_all = jnp.concatenate([vs_pre, vs_chunk], axis=1)
     o = flash_attention_verify(q, k_all, v_all, kpos, pos=pos,
                                shift=length, k_scale=ks_all,
                                v_scale=vs_all, backend=backend)

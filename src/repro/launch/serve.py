@@ -35,12 +35,15 @@ import argparse
 import collections
 import contextlib
 import dataclasses
+import itertools
 import json
 import logging
 import time
 from typing import List, Optional
 
 import numpy as np
+
+from repro.launch import tracing
 
 
 # ---------------------------------------------------------------------------
@@ -65,6 +68,7 @@ class Request:
     #                                          restarts at each retry)
     # filled by the engine:
     tokens: list = dataclasses.field(default_factory=list)
+    t_sched: float = -1.0         # last pick by schedule_admissions
     t_admit: float = -1.0
     t_first: float = -1.0
     t_done: float = -1.0
@@ -1000,6 +1004,10 @@ class ServeEngine:
         self.req_of: List[Optional[Request]] = [None] * n_slots
         self.step_count = 0
         self.prefill_tokens = 0
+        # token rows the admission prefill computed: n_slots x the chunk
+        # grid's tokens (padding included, skipped chunks not), and the
+        # prompt tokens among them
+        self.prefill_token_rows = self.prefill_tokens_computed = 0
         self.decode_tokens = 0
         self.prefill_wall = 0.0
         self.occupancy: List[float] = []
@@ -1016,6 +1024,9 @@ class ServeEngine:
         self.sheds_admission = self.sheds_decode = self.retries = 0
         self.admission_alloc_failures = 0
         self.injected_alloc_failures = self.forced_preemptions = 0
+        # schedule_admissions calls that left a due request queued: its
+        # page reservation failed / no slot was free
+        self.admit_blocked_pages = self.admit_blocked_slots = 0
         self.queue_depths: List[int] = []
         self._alloc_calls = 0
         self._fault_held: List[int] = []
@@ -1217,7 +1228,16 @@ class ServeEngine:
         page demand, and a head that doesn't fit blocks the line (no
         starvation — pool drain admits it first).  Retry-backoff entries
         whose effective arrival hasn't come are skipped, not blocking.
-        TTFT-deadline misses shed here, before burning a prefill."""
+        TTFT-deadline misses shed here, before burning a prefill.
+
+        A picked request's ``t_sched`` is set to ``now``.  A call that
+        leaves a due request queued counts in ``admit_blocked_pages``
+        (its reservation failed) or ``admit_blocked_slots`` (no slot was
+        free)."""
+        with tracing.span("serve.schedule"):
+            return self._schedule(now)
+
+    def _schedule(self, now: float) -> List[tuple]:
         self.queue_depths.append(len(self.queue))
         pairs: List[tuple] = []
         free_slots = [j for j in range(self.n_slots)
@@ -1235,11 +1255,17 @@ class ServeEngine:
                 continue
             need = self._need_pages(req)
             if self.paged and not self.alloc.reserve(need):
+                self.admit_blocked_pages += 1
                 break           # head-of-line waits for pool drain
             j = free_slots.pop(0)
             self.resv_of[j] = need
             del self.queue[i]
+            req.t_sched = now
             pairs.append((req, j))
+        if not free_slots and any(
+                r.eff_arrival <= now
+                for r in itertools.islice(self.queue, i, None)):
+            self.admit_blocked_slots += 1
         return pairs
 
     def _release_reservation(self, j: int) -> None:
@@ -1381,7 +1407,7 @@ class ServeEngine:
         self.pt_host[j] = row
         return cov
 
-    def _prefill_group(self, pairs: List[tuple], shared=None):
+    def _prefill_group(self, pairs: List[tuple], shared, rid_arg: str):
         """Chunked flash prefill for up to ``n_slots`` requests in ONE
         batched call chain (effective prompts right-padded to a shared
         chunk grid, rows beyond len(pairs) are dummies) — admission costs
@@ -1392,7 +1418,8 @@ class ServeEngine:
         ``admit`` before this call; ``shared`` carries each row's prefix
         coverage, and any chunk every row's coverage already spans — and
         that holds no row's last prompt token — is skipped outright: its
-        KV already sits in the shared pages."""
+        KV already sits in the shared pages.  ``rid_arg`` names the group
+        in its spans."""
         jnp = self.jnp
         prompts = [_eff_prompt(r) for r, _ in pairs]
         toks, plens, grid = _pad_group(prompts, self.n_slots, self.chunk,
@@ -1414,19 +1441,25 @@ class ServeEngine:
                            for pl, sh in zip(plens[:len(pairs)], shared)):
                         skip.add(p0)
                 self.prefill_chunks_skipped += len(skip)
-        last, cache = _chunked_prefill(self.prefill_step, self.params,
-                                       in_cache, toks, plens, grid,
-                                       skip=skip)
+        chunks = [(p0, c) for p0, c in grid if p0 not in skip]
+        self.prefill_token_rows += self.n_slots * sum(c for _, c in chunks)
+        self.prefill_tokens_computed += sum(
+            max(0, min(pl, p0 + c) - p0) for pl in plens for p0, c in chunks)
+        with tracing.span("serve.admit.prefill", rids=rid_arg):
+            last, cache = _chunked_prefill(self.prefill_step, self.params,
+                                           in_cache, toks, plens, grid,
+                                           skip=skip)
         self._group_cache = cache
         # first token at logical position plen draws from the (rid, plen)
         # stream — same derivation every later decode/verify sample uses
         rids = np.zeros(self.n_slots, np.int32)
         for i, (r, _) in enumerate(pairs):
             rids[i] = r.rid
-        first = self.sample_first(jnp.asarray(last), self.base_key,
-                                  jnp.asarray(rids),
-                                  jnp.asarray(plens, dtype=np.int32))
-        return np.asarray(first), cache
+        with tracing.span("serve.admit.first_tokens", rids=rid_arg):
+            first = self.sample_first(jnp.asarray(last), self.base_key,
+                                      jnp.asarray(rids),
+                                      jnp.asarray(plens, dtype=np.int32))
+            return np.asarray(first), cache
 
     def _prefill_loop(self, req: Request, key):
         """Recurrent caches: token-by-token loop on a single-row cache."""
@@ -1453,9 +1486,13 @@ class ServeEngine:
         hits pool exhaustion is unwound (no leak) and requeued at the
         queue front — it drops out of this admission group instead of
         crashing it."""
+        if not pairs:
+            return []
         t0 = self.now()
+        rid_arg = tracing.ids(r.rid for r, _ in pairs)
         try:
-            return self._admit(pairs, now)
+            with tracing.span("serve.admit", rids=rid_arg):
+                return self._admit(pairs, now, rid_arg)
         finally:
             # admission wall (prompt prefill + mapping + bookkeeping)
             # accumulates separately so _report can expose a decode-only
@@ -1463,30 +1500,33 @@ class ServeEngine:
             # dilute decode-path comparisons with identical prefill cost
             self.prefill_wall += self.now() - t0
 
-    def _admit(self, pairs: List[tuple], now: float) -> List[Request]:
-        if not pairs:
-            return []
+    def _admit(self, pairs: List[tuple], now: float,
+               rid_arg: str) -> List[Request]:
         shared = None
         if self.paged:
             kept, shared = [], []
-            for req, j in pairs:
-                cov = self._map_prompt_pages(req, j)
-                if cov is None:
-                    self.admission_alloc_failures += 1
-                    self.requeues += 1
-                    req.eff_arrival = min(req.eff_arrival, now) \
-                        if req.eff_arrival >= 0 else now
-                    self.queue.appendleft(req)
-                else:
-                    kept.append((req, j))
-                    shared.append(cov)
-            pairs = kept
-            if not pairs:
+            with tracing.span("serve.admit.map_pages", rids=rid_arg):
+                for req, j in pairs:
+                    cov = self._map_prompt_pages(req, j)
+                    if cov is None:
+                        self.admission_alloc_failures += 1
+                        self.requeues += 1
+                        req.eff_arrival = min(req.eff_arrival, now) \
+                            if req.eff_arrival >= 0 else now
+                        self.queue.appendleft(req)
+                    else:
+                        kept.append((req, j))
+                        shared.append(cov)
+            if not kept:
                 return []
+            if len(kept) < len(pairs):
+                rid_arg = tracing.ids(r.rid for r, _ in kept)
+            pairs = kept
         if self.prefill_step is not None:
-            first, cache = self._prefill_group(pairs, shared)
-            self._write_rows(cache, [(i, j) for i, (_, j)
-                                     in enumerate(pairs)])
+            first, cache = self._prefill_group(pairs, shared, rid_arg)
+            with tracing.span("serve.admit.write_rows", rids=rid_arg):
+                self._write_rows(cache, [(i, j) for i, (_, j)
+                                         in enumerate(pairs)])
             firsts = [int(first[i]) for i in range(len(pairs))]
         else:
             firsts = []
@@ -1494,6 +1534,8 @@ class ServeEngine:
                 k = self.jax.random.fold_in(
                     self.base_key, np.uint32(2 ** 31 + r.rid))
                 f, cache = self._prefill_loop(r, k)
+                self.prefill_token_rows += len(_eff_prompt(r))
+                self.prefill_tokens_computed += len(_eff_prompt(r))
                 self._write_rows(cache, [(0, j)])
                 firsts.append(f)
         finished = []
@@ -1814,6 +1856,10 @@ class ServeEngine:
         k of 1 — the shape-stable degenerate case)."""
         if self.spec != "off":
             return self._spec_step_all()
+        with tracing.span("serve.decode"):
+            return self._decode_step()
+
+    def _decode_step(self):
         jnp = self.jnp
         step = self.step_count
         now = self.now()
@@ -1833,45 +1879,57 @@ class ServeEngine:
             if forced and self.paged:
                 self._push_pt()
         if self.paged:
-            # the step writes row pos[j] of each active slot: grow the
-            # table a page at a time, and fork (COW) any still-shared page
-            # the write would land in
-            dirty = False
-            for j in range(self.n_slots):
-                if self.req_of[j] is None:
-                    continue
-                idx = int(self.pos[j]) // self.page_size
-                page = int(self.pt_host[j, idx])
-                if page < 0:
-                    p = self._alloc_with_preemption(j)
-                    if p is None:
-                        dirty = True        # j preempted itself
-                        continue
-                    self.pt_host[j, idx] = p
-                    self.pages_requested += 1
-                    self.pages_alloced += 1
-                    dirty = True
-                elif self.alloc.ref[page] > 1:
-                    p = self._alloc_with_preemption(j)
-                    if p is None:
-                        dirty = True
-                        continue
-                    # re-read: a preemption inside the alloc may have
-                    # dropped other references and un-shared the page
-                    page = int(self.pt_host[j, idx])
-                    if page >= 0 and self.alloc.ref[page] > 1:
-                        self.pt_host[j, idx] = self._cow_into(page, p)
-                    else:
-                        self.alloc.decref(p)    # fork no longer needed
-                    dirty = True
-            if dirty:
-                self._push_pt()
-        tok, _, self.cache = self.serve_step(
-            self.params, self.cache,
-            {"tokens": jnp.asarray(self.tok[:, None])},
-            jnp.asarray(self.pos), self.base_key, self._sids())
+            with tracing.span("serve.decode.pages"):
+                self._grow_pages()
+        with tracing.span("serve.decode.dispatch"):
+            tok, _, self.cache = self.serve_step(
+                self.params, self.cache,
+                {"tokens": jnp.asarray(self.tok[:, None])},
+                jnp.asarray(self.pos), self.base_key, self._sids())
         self.step_count += 1
-        tok = np.asarray(tok)
+        with tracing.span("serve.decode.wait"):
+            tok = np.asarray(tok)
+        with tracing.span("serve.decode.retire"):
+            return self._retire(tok, now)
+
+    def _grow_pages(self) -> None:
+        """The step writes row pos[j] of each active slot: grow the table
+        a page at a time, fork (COW) any still-shared page the write would
+        land in, and push the table if it changed."""
+        dirty = False
+        for j in range(self.n_slots):
+            if self.req_of[j] is None:
+                continue
+            idx = int(self.pos[j]) // self.page_size
+            page = int(self.pt_host[j, idx])
+            if page < 0:
+                p = self._alloc_with_preemption(j)
+                if p is None:
+                    dirty = True        # j preempted itself
+                    continue
+                self.pt_host[j, idx] = p
+                self.pages_requested += 1
+                self.pages_alloced += 1
+                dirty = True
+            elif self.alloc.ref[page] > 1:
+                p = self._alloc_with_preemption(j)
+                if p is None:
+                    dirty = True
+                    continue
+                # re-read: a preemption inside the alloc may have dropped
+                # other references and un-shared the page
+                page = int(self.pt_host[j, idx])
+                if page >= 0 and self.alloc.ref[page] > 1:
+                    self.pt_host[j, idx] = self._cow_into(page, p)
+                else:
+                    self.alloc.decref(p)    # fork no longer needed
+                dirty = True
+        if dirty:
+            self._push_pt()
+
+    def _retire(self, tok: np.ndarray, now: float) -> List[Request]:
+        """Append each active slot's new token; free the slots of finished
+        and total-deadline-shed requests."""
         finished = []
         freed_any = False
         for j in range(self.n_slots):
@@ -1931,6 +1989,7 @@ class ServeEngine:
         self.req_of = [None] * self.n_slots
         self.step_count = 0
         self.prefill_tokens = self.decode_tokens = 0
+        self.prefill_token_rows = self.prefill_tokens_computed = 0
         self.prefill_wall = 0.0
         self.occupancy = []
         if self.paged:
@@ -1951,6 +2010,7 @@ class ServeEngine:
         self.sheds_admission = self.sheds_decode = self.retries = 0
         self.admission_alloc_failures = 0
         self.injected_alloc_failures = self.forced_preemptions = 0
+        self.admit_blocked_pages = self.admit_blocked_slots = 0
         self.queue_depths = []
         self._alloc_calls = 0
         self._t0 = None

@@ -305,6 +305,15 @@ def _rope_tables(cfg: ModelConfig, batch, s: int):
     return cm.rope_cos_sin(positions, cfg.hd, cfg.rope_theta)
 
 
+def _lm_head(cfg: ModelConfig, params: Params, x) -> jnp.ndarray:
+    """Logits of the final hidden states, under the ``lm_head`` scope, so
+    that a device trace can time the head apart from the layers."""
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return x @ params["embed"]["table"].T.astype(x.dtype)
+        return cm.linear(params["lm_head"], x, dtype=x.dtype)
+
+
 def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jnp.ndarray]
             ) -> Dict[str, jnp.ndarray]:
     params = cast_params(cfg, params)
@@ -355,10 +364,7 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jnp.ndarray]
 
     x = cm.apply_norm(cfg.norm, params["final_norm"], x)
     out = {"aux_loss": aux}
-    if cfg.tie_embeddings:
-        out["logits"] = (x @ params["embed"]["table"].T.astype(x.dtype))
-    else:
-        out["logits"] = cm.linear(params["lm_head"], x, dtype=x.dtype)
+    out["logits"] = _lm_head(cfg, params, x)
     if cfg.value_head:
         out["value"] = cm.linear(params["value_head"], x)[..., 0] \
             .astype(jnp.float32)
@@ -449,10 +455,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
 
     x = cm.apply_norm(cfg.norm, params["final_norm"], x)
     out = {}
-    if cfg.tie_embeddings:
-        out["logits"] = (x @ params["embed"]["table"].T.astype(x.dtype))
-    else:
-        out["logits"] = cm.linear(params["lm_head"], x, dtype=x.dtype)
+    out["logits"] = _lm_head(cfg, params, x)
     if cfg.value_head:
         out["value"] = cm.linear(params["value_head"], x)[..., 0] \
             .astype(jnp.float32)
@@ -530,10 +533,7 @@ def verify_step(cfg: ModelConfig, params: Params, cache: Params,
 
     x = cm.apply_norm(cfg.norm, params["final_norm"], x)
     out = {}
-    if cfg.tie_embeddings:
-        out["logits"] = (x @ params["embed"]["table"].T.astype(x.dtype))
-    else:
-        out["logits"] = cm.linear(params["lm_head"], x, dtype=x.dtype)
+    out["logits"] = _lm_head(cfg, params, x)
     return out, pendings
 
 
@@ -639,10 +639,7 @@ def prefill_step(cfg: ModelConfig, params: Params, cache: Params,
 
     x = cm.apply_norm(cfg.norm, params["final_norm"], x)
     out = {}
-    if cfg.tie_embeddings:
-        out["logits"] = (x @ params["embed"]["table"].T.astype(x.dtype))
-    else:
-        out["logits"] = cm.linear(params["lm_head"], x, dtype=x.dtype)
+    out["logits"] = _lm_head(cfg, params, x)
     if cfg.value_head:
         out["value"] = cm.linear(params["value_head"], x)[..., 0] \
             .astype(jnp.float32)
