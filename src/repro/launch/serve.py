@@ -309,22 +309,15 @@ def _chunk_grid(pmax: int, chunk: int, cache_len: int) -> List[tuple]:
     return grid
 
 
-def _pad_group(prompts: List[np.ndarray], n_rows: int, chunk: int,
-               cache_len: int):
-    """Right-pad a group of prompt arrays onto the shared chunk grid.
-    Returns (toks (n_rows, padded) int32, plens, grid); rows beyond
-    len(prompts) are dummies with plen 0.  (Takes raw token arrays, not
-    Requests: a requeued request prefills its EFFECTIVE prompt — original
-    plus generated-so-far — via ``_eff_prompt``.)"""
-    pmax = max((len(p) for p in prompts), default=1)
-    grid = _chunk_grid(pmax, chunk, cache_len)
-    padded = grid[-1][0] + grid[-1][1]
-    toks = np.zeros((n_rows, padded), np.int32)
-    plens = [0] * n_rows
-    for i, p in enumerate(prompts):
-        toks[i, :len(p)] = p
-        plens[i] = len(p)
-    return toks, plens, grid
+def _pad_prompt(prompt: np.ndarray, chunk: int, cache_len: int):
+    """Right-pad one prompt array onto its chunk grid.  Returns (toks
+    (1, padded) int32, plens [len(prompt)], grid).  (Takes a raw token
+    array, not a Request: a requeued request prefills its EFFECTIVE
+    prompt — original plus generated-so-far — via ``_eff_prompt``.)"""
+    grid = _chunk_grid(len(prompt), chunk, cache_len)
+    toks = np.zeros((1, grid[-1][0] + grid[-1][1]), np.int32)
+    toks[0, :len(prompt)] = prompt
+    return toks, [len(prompt)], grid
 
 
 def _chunked_prefill(prefill_step, params, cache, toks, plens, grid,
@@ -782,8 +775,8 @@ class DraftModel:
     def warm_prefill(self, plen: int) -> None:
         """Compile every chunk offset a ``plen``-token admission can
         reach (called from the engine's warmup, outside timed regions)."""
-        toks, plens, grid = _pad_group([np.zeros(plen, np.int32)], 1,
-                                       self.chunk, self.cache_len)
+        toks, plens, grid = _pad_prompt(np.zeros(plen, np.int32),
+                                        self.chunk, self.cache_len)
         cache = self.M.init_cache(self.cfg, 1, self.cache_len,
                                   dtype=self.jnp.float32)
         _chunked_prefill(self.prefill, self.params, cache, toks, plens,
@@ -794,8 +787,8 @@ class DraftModel:
         (generated tokens fold in on a preempted restore, so the draft
         frontier re-syncs to the committed token stream)."""
         prompt = _eff_prompt(req)
-        toks, plens, grid = _pad_group([prompt], 1, self.chunk,
-                                       self.cache_len)
+        toks, plens, grid = _pad_prompt(prompt, self.chunk,
+                                        self.cache_len)
         cache = self.M.init_cache(self.cfg, 1, self.cache_len,
                                   dtype=self.jnp.float32)
         _, cache = _chunked_prefill(self.prefill, self.params, cache,
@@ -870,11 +863,11 @@ class ServeEngine:
     """Slot table + schedulers around one jitted per-slot ``serve_step``.
 
     The model cache is one batched pytree of ``n_slots`` rows; admission
-    prefills the whole arrived group in one batch-``n_slots`` chunk chain
-    (recurrent archs: a token loop per request) and lands each row in its
-    freed slot via a single jitted masked-permutation write — generic over
-    every cache kind, KV and recurrent alike (the batch dim per leaf is
-    found once by diffing eval_shapes).
+    prefills each request of the arrived group on one row, in a chunk
+    chain of its own (recurrent archs: a token loop), and lands the row in
+    its freed slot via a single jitted masked write — generic over every
+    cache kind, KV and recurrent alike (the batch dim per leaf is found
+    once by diffing eval_shapes).
     """
 
     def __init__(self, cfg, params, *, n_slots: int, cache_len: int,
@@ -1004,8 +997,8 @@ class ServeEngine:
         self.req_of: List[Optional[Request]] = [None] * n_slots
         self.step_count = 0
         self.prefill_tokens = 0
-        # token rows the admission prefill computed: n_slots x the chunk
-        # grid's tokens (padding included, skipped chunks not), and the
+        # token rows the admission prefill computed: each request's chunk
+        # grid tokens (padding included, skipped chunks not), and the
         # prompt tokens among them
         self.prefill_token_rows = self.prefill_tokens_computed = 0
         self.decode_tokens = 0
@@ -1014,6 +1007,8 @@ class ServeEngine:
         self.page_occupancy: List[float] = []
         self.pages_requested = self.pages_alloced = 0
         self.cow_events = self.prefill_chunks_skipped = 0
+        # requests the chunked prefill admitted, each on one row
+        self.prefill_one_row_admissions = 0
         # robustness state: arrival queue (backpressure holds requests
         # here instead of admitting them into doomed slots), per-slot
         # outstanding reservation units, terminal sheds, counters
@@ -1056,31 +1051,38 @@ class ServeEngine:
                 return -3
             return bd
         self._bdim = jax.tree_util.tree_map_with_path(kind_of, bdim)
-        # persistent admission-prefill cache (batch n_slots): stale rows
-        # beyond a new request's prompt are hidden by the kpos/pos
-        # invariant, so it never needs re-zeroing
-        self._group_cache = M.init_cache(cfg, n_slots, cache_len,
-                                         dtype=jnp.float32,
-                                         paged=self.paged_layout,
-                                         kv_dtype=self.kv_dtype)
         bdims = self._bdim
 
-        def scatter(big, small, perm, mask):
-            """big[j] <- small[perm[j]] where mask[j], per cache leaf —
-            the whole admission scatter is one jitted call."""
+        def group_cache():
+            """The admission prefill's one-row input cache.  Its pool
+            leaves are empty (``build_group`` hands the prefill the
+            engine's pool), so it never holds a second pool; the prefill's
+            output is not kept, and stale rows would be hidden by the
+            kpos/pos invariant anyway."""
+            c = M.init_cache(cfg, 1, cache_len, dtype=jnp.float32,
+                             paged=self.paged_layout,
+                             kv_dtype=self.kv_dtype)
+            return jax.tree.map(
+                lambda bd, x: jnp.zeros((0,), x.dtype) if bd == -2 else x,
+                bdims, c)
+
+        self._group_cache = None if self.prefill_step is None \
+            else jax.jit(group_cache)()
+
+        def scatter(big, small, mask):
+            """big[j] <- small[0] where mask[j], per cache leaf (``small``
+            is one row) — landing an admitted row is one jitted call."""
             def one(bd, b, s):
                 if bd == -2:
-                    return s    # shared pool: the group's writes ARE the
+                    return s    # shared pool: the prefill's writes ARE the
                                 # engine's (one physical pool)
                 if bd == -3:
                     bd = b.ndim - 2   # page table (…, n_slots, max_pages)
                 if bd < 0:
                     return b    # engine tracks per-slot pos itself
-                idx = jnp.clip(perm, 0, s.shape[bd] - 1)
-                taken = jnp.take(s, idx, axis=bd).astype(b.dtype)
                 shape = [1] * b.ndim
                 shape[bd] = -1
-                return jnp.where(mask.reshape(shape), taken, b)
+                return jnp.where(mask.reshape(shape), s.astype(b.dtype), b)
             return jax.tree.map(one, bdims, big, small)
 
         self._scatter = jax.jit(scatter)
@@ -1089,8 +1091,7 @@ class ServeEngine:
             """Assemble the admission-prefill input cache: shared pools
             from the ENGINE cache (decode wrote pages since the last
             admission), page tables from the admission mapping, and
-            contiguous / recurrent leaves from the persistent group
-            cache."""
+            contiguous / recurrent leaves from the group cache."""
             def one(bd, g, e):
                 if bd == -2:
                     return e
@@ -1342,16 +1343,12 @@ class ServeEngine:
 
     # -- admission ----------------------------------------------------------
 
-    def _write_rows(self, group_cache, row_to_slot):
-        """Scatter rows of an admission-prefill cache into their assigned
-        engine-cache slots (one jitted masked-permutation write)."""
-        perm = np.zeros(self.n_slots, np.int32)
+    def _write_row(self, row_cache, j: int) -> None:
+        """Land a one-row admission cache in engine slot ``j`` (one jitted
+        masked write)."""
         mask = np.zeros(self.n_slots, bool)
-        for i, j in row_to_slot:
-            perm[j] = i
-            mask[j] = True
-        self.cache = self._scatter(self.cache, group_cache,
-                                   self.jnp.asarray(perm),
+        mask[j] = True
+        self.cache = self._scatter(self.cache, row_cache,
                                    self.jnp.asarray(mask))
 
     def _map_prompt_pages(self, req: Request, j: int) -> Optional[int]:
@@ -1407,67 +1404,71 @@ class ServeEngine:
         self.pt_host[j] = row
         return cov
 
-    def _prefill_group(self, pairs: List[tuple], shared, rid_arg: str):
-        """Chunked flash prefill for up to ``n_slots`` requests in ONE
-        batched call chain (effective prompts right-padded to a shared
-        chunk grid, rows beyond len(pairs) are dummies) — admission costs
-        the same kernel launches as a full lockstep wave, shape-stable
-        across group sizes.  Returns (first_tokens (n_slots,), cache).
+    def _prefill_input(self, j: Optional[int] = None):
+        """The one-row prefill's input cache: the group cache, plus (paged
+        layout) the engine's pools and slot ``j``'s page-table row — all
+        unmapped for None, so the writes land in the page-0 sink of a
+        copy of the pool."""
+        if not self.paged:
+            return self._group_cache
+        row = self.pt_host[j:j + 1] if j is not None else \
+            np.full((1, self.max_pages), -1, np.int32)
+        return self._build_group(self._group_cache, self.cache,
+                                 self.jnp.asarray(row))
 
-        Paged layout: page tables were mapped (with prefix reuse) by
-        ``admit`` before this call; ``shared`` carries each row's prefix
-        coverage, and any chunk every row's coverage already spans — and
-        that holds no row's last prompt token — is skipped outright: its
-        KV already sits in the shared pages.  ``rid_arg`` names the group
-        in its spans."""
+    def _prefill_one(self, req: Request, j: int, cov: int, rid_arg: str):
+        """Chunked flash prefill of one admitted request on one row: its
+        effective prompt right-padded to its own chunk grid.  Returns
+        (first token, cache).  One row per request keeps the prefill
+        programs to one per chunk offset, and a lone request (most
+        admissions) pays for no padding rows.
+
+        Paged layout: slot ``j``'s page table was mapped (with prefix
+        reuse) by ``admit`` before this call; ``cov`` is the prompt's prefix
+        coverage, and a chunk it spans whole — and that holds not the last
+        prompt token — is skipped outright: its KV already sits in the
+        shared pages (an earlier request of this group wrote them first).
+        ``rid_arg`` names the group in its spans."""
         jnp = self.jnp
-        prompts = [_eff_prompt(r) for r, _ in pairs]
-        toks, plens, grid = _pad_group(prompts, self.n_slots, self.chunk,
-                                       self.cache_len)
+        prompt = _eff_prompt(req)
+        plen = len(prompt)
+        toks, plens, grid = _pad_prompt(prompt, self.chunk, self.cache_len)
         skip: set = set()
-        in_cache = self._group_cache
-        if self.paged:
-            pt_rows = np.full((self.n_slots, self.max_pages), -1, np.int32)
-            for i, (_, j) in enumerate(pairs):
-                pt_rows[i] = self.pt_host[j]
-            in_cache = self._build_group(self._group_cache, self.cache,
-                                         jnp.asarray(pt_rows))
-            if self.prefix_cache and all(
-                    k == "attn" for k in self.cfg.layer_kinds()):
-                # ring layers keep contiguous caches that need every
-                # chunk, so skipping is global-attention-only
-                for p0, c in grid:
-                    if all(pl <= p0 or (sh >= p0 + c and pl - 1 >= p0 + c)
-                           for pl, sh in zip(plens[:len(pairs)], shared)):
-                        skip.add(p0)
-                self.prefill_chunks_skipped += len(skip)
+        if self.paged and self.prefix_cache and all(
+                k == "attn" for k in self.cfg.layer_kinds()):
+            # ring layers keep contiguous caches that need every chunk, so
+            # skipping is global-attention-only
+            skip = {p0 for p0, c in grid
+                    if cov >= p0 + c and plen - 1 >= p0 + c}
+            self.prefill_chunks_skipped += len(skip)
         chunks = [(p0, c) for p0, c in grid if p0 not in skip]
-        self.prefill_token_rows += self.n_slots * sum(c for _, c in chunks)
-        self.prefill_tokens_computed += sum(
-            max(0, min(pl, p0 + c) - p0) for pl in plens for p0, c in chunks)
+        self.prefill_one_row_admissions += 1
+        self.prefill_token_rows += sum(c for _, c in chunks)
+        self.prefill_tokens_computed += sum(min(plen, p0 + c) - p0
+                                            for p0, c in chunks)
         with tracing.span("serve.admit.prefill", rids=rid_arg):
             last, cache = _chunked_prefill(self.prefill_step, self.params,
-                                           in_cache, toks, plens, grid,
-                                           skip=skip)
-        self._group_cache = cache
+                                           self._prefill_input(j), toks,
+                                           plens, grid, skip=skip)
         # first token at logical position plen draws from the (rid, plen)
         # stream — same derivation every later decode/verify sample uses
-        rids = np.zeros(self.n_slots, np.int32)
-        for i, (r, _) in enumerate(pairs):
-            rids[i] = r.rid
         with tracing.span("serve.admit.first_tokens", rids=rid_arg):
             first = self.sample_first(jnp.asarray(last), self.base_key,
-                                      jnp.asarray(rids),
-                                      jnp.asarray(plens, dtype=np.int32))
-            return np.asarray(first), cache
+                                      jnp.asarray([req.rid], np.int32),
+                                      jnp.asarray(plens, np.int32))
+            return int(np.asarray(first)[0]), cache
 
-    def _prefill_loop(self, req: Request, key):
+    def _prefill_loop(self, req: Request):
         """Recurrent caches: token-by-token loop on a single-row cache."""
         jnp = self.jnp
+        key = self.jax.random.fold_in(self.base_key,
+                                      np.uint32(2 ** 31 + req.rid))
         cache = self.M.init_cache(self.cfg, 1, self.cache_len,
                                   dtype=jnp.float32,
                                   kv_dtype=self.kv_dtype)
         prompt = _eff_prompt(req)
+        self.prefill_token_rows += len(prompt)
+        self.prefill_tokens_computed += len(prompt)
         for i in range(len(prompt)):
             tok, _, cache = self.serve_step(
                 self.params, cache,
@@ -1522,22 +1523,19 @@ class ServeEngine:
             if len(kept) < len(pairs):
                 rid_arg = tracing.ids(r.rid for r, _ in kept)
             pairs = kept
-        if self.prefill_step is not None:
-            first, cache = self._prefill_group(pairs, shared, rid_arg)
+        # one request at a time, each row landing before the next prefill
+        # reads the pool: a later request may map pages an earlier one of
+        # this group wrote
+        firsts = []
+        for i, (r, j) in enumerate(pairs):
+            if self.prefill_step is not None:
+                f, cache = self._prefill_one(r, j, shared[i] if shared
+                                             else 0, rid_arg)
+            else:
+                f, cache = self._prefill_loop(r)
             with tracing.span("serve.admit.write_rows", rids=rid_arg):
-                self._write_rows(cache, [(i, j) for i, (_, j)
-                                         in enumerate(pairs)])
-            firsts = [int(first[i]) for i in range(len(pairs))]
-        else:
-            firsts = []
-            for r, j in pairs:
-                k = self.jax.random.fold_in(
-                    self.base_key, np.uint32(2 ** 31 + r.rid))
-                f, cache = self._prefill_loop(r, k)
-                self.prefill_token_rows += len(_eff_prompt(r))
-                self.prefill_tokens_computed += len(_eff_prompt(r))
-                self._write_rows(cache, [(0, j)])
-                firsts.append(f)
+                self._write_row(cache, j)
+            firsts.append(f)
         finished = []
         freed = False
         for (req, j), f in zip(pairs, firsts):
@@ -2000,6 +1998,7 @@ class ServeEngine:
         self.page_occupancy = []
         self.pages_requested = self.pages_alloced = 0
         self.cow_events = self.prefill_chunks_skipped = 0
+        self.prefill_one_row_admissions = 0
         # robustness state: clear queue/sheds/counters, restart the fault
         # injector's deterministic counters, re-seize standing pressure on
         # the fresh allocator
@@ -2031,9 +2030,9 @@ class ServeEngine:
 
 def _warmup(eng: ServeEngine, trace: List[Request]) -> float:
     """Compile everything the run can hit, outside the timed region: every
-    prefill chunk offset the trace can reach (admission prefills are
-    always batch = n_slots, so these are exactly the run's shapes), the
-    first-token sampler, and one decode step.
+    prefill chunk offset the trace can reach (admission prefills always
+    run one row a request, so these are exactly the run's shapes), the
+    first-token sampler, the row scatter, and one decode step.
 
     Fault injection is suspended for the warmup pass (its deterministic
     call counters restart at reset anyway) so the warm request always
@@ -2052,18 +2051,12 @@ def _warmup(eng: ServeEngine, trace: List[Request]) -> float:
             pmax = min(eng.cache_len,
                        max((len(r.prompt) + r.max_new - 1 for r in trace),
                            default=1))
-        toks, plens, grid = _pad_group(
-            [np.zeros(pmax, np.int32)], eng.n_slots, eng.chunk,
-            eng.cache_len)
-        # paged warmup cache compiles the real (pool + table) shapes; its
-        # all-unmapped tables route every write to the page-0 sink and
-        # every read through fully-masked kpos — numerically safe garbage
-        wc = eng.M.init_cache(eng.cfg, eng.n_slots, eng.cache_len,
-                              dtype=eng.jnp.float32,
-                              paged=eng.paged_layout,
-                              kv_dtype=eng.kv_dtype)
-        _chunked_prefill(eng.prefill_step, eng.params, wc, toks, plens,
-                         grid)
+        # the engine's own pool, through unmapped page tables: the warm
+        # writes land in the page-0 sink of a copy of it
+        toks, plens, grid = _pad_prompt(np.zeros(pmax, np.int32), eng.chunk,
+                                        eng.cache_len)
+        _chunked_prefill(eng.prefill_step, eng.params, eng._prefill_input(),
+                         toks, plens, grid)
         if eng.spec == "draft":
             # draft admissions are single-row prefills over the same
             # chunk grid — compile those offsets too
@@ -2143,6 +2136,7 @@ def _report(mode: str, eng: ServeEngine, done: List[Request], wall: float,
         "warmup_s": round(warmup_s, 3),
         "wall_s": round(wall, 3),
         "prefill_tokens": eng.prefill_tokens,
+        "prefill_one_row_admissions": eng.prefill_one_row_admissions,
         "generated_tokens": total_new,
         "tokens_per_s": round(total_new / wall, 1) if wall else 0.0,
         # decode-phase rate: admission (prefill) wall subtracted, so legs

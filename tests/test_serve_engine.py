@@ -177,6 +177,57 @@ def test_engine_ring_arch_chunked_prefill_matches():
         assert r.tokens == want, (r.rid, r.tokens, want)
 
 
+@pytest.mark.parametrize("layout", ["paged", "ring"])
+def test_lone_request_prefills_one_row(layout):
+    """Admission prefills one row a request: a lone request, also into
+    the second slot while the first decodes, and each request of a group
+    of two on a row of its own.  Every request matches per-request
+    sequential greedy decode."""
+    cfg = _cfg()
+    if layout == "ring":
+        cfg = dataclasses.replace(cfg, block_cycle=("attn_local",),
+                                  sliding_window=8)
+    params = M.init_params(cfg, jax.random.key(0))
+    cache_len, chunk = 32, 8
+    eng = serve_mod.ServeEngine(cfg, params, n_slots=2, cache_len=cache_len,
+                                chunk=chunk, sample=False, seed=0,
+                                page_size=8)
+    assert eng.paged == (layout == "paged")
+    reqs = serve_mod.gen_trace(4, vocab=cfg.vocab_size,
+                               prompt_range=(4, 20), gen_range=(3, 6),
+                               arrival_rate=0.0, seed=7)
+
+    def grid_tokens(rs):
+        pmax = max(len(r.prompt) for r in rs)
+        return sum(c for _, c in serve_mod._chunk_grid(pmax, chunk,
+                                                       cache_len))
+
+    def admit(rs):
+        rows0 = eng.prefill_token_rows
+        for r in rs:
+            eng.enqueue(r)
+        pairs = eng.schedule_admissions(0.0)
+        assert [r for r, _ in pairs] == rs
+        eng.admit(pairs, 0.0)
+        return eng.prefill_token_rows - rows0
+
+    assert admit(reqs[:1]) == grid_tokens(reqs[:1])
+    eng.decode_step_all()
+    assert eng.req_of[0] is reqs[0]
+    assert admit(reqs[1:2]) == grid_tokens(reqs[1:2])
+    assert eng.prefill_one_row_admissions == 2
+    while any(r is not None for r in eng.req_of):
+        eng.decode_step_all()
+    assert admit(reqs[2:]) == grid_tokens(reqs[2:3]) + grid_tokens(reqs[3:])
+    assert eng.prefill_one_row_admissions == 4
+    while any(r is not None for r in eng.req_of):
+        eng.decode_step_all()
+    for r in reqs:
+        want = _reference_greedy(cfg, params, r.prompt, r.max_new,
+                                 cache_len)
+        assert r.tokens == want, (r.rid, r.tokens, want)
+
+
 def test_engine_fallback_loop_prefill():
     """Recurrent-cache arch: the engine falls back to token-by-token
     prefill and still matches sequential greedy decode."""

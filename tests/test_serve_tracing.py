@@ -166,18 +166,19 @@ def test_t_sched_takes_the_last_pick_after_a_requeue(model):
 
 
 def test_prefill_token_rows(model):
-    """n_slots x the chunk grid's tokens, padding in, skipped chunks out;
-    beside them the prompt tokens the prefill computed, so the padding
-    share holds on prefix hits."""
+    """Each request's chunk grid tokens on its one row, padding in,
+    skipped chunks out; beside them the prompt tokens the prefill
+    computed, so the padding share holds on prefix hits."""
     cfg, params = model
     eng = _engine(cfg, params)
     a, b = _req(1, 100, max_new=1), _req(2, 200, max_new=1)
     for r in (a, b):
         eng.enqueue(r)
     eng.admit(eng.schedule_admissions(0.0), 0.0)
-    # one group: longest prompt 200 -> chunks (0, 128), (128, 128)
+    # one group, one row a request: 100 -> chunk (0, 128), 200 -> chunks
+    # (0, 128), (128, 128)
     assert eng.prefill_tokens == 300
-    assert eng.prefill_token_rows == 2 * 256
+    assert eng.prefill_token_rows == 128 + 256
     assert eng.prefill_tokens_computed == 300
     # a 300-token prompt held in a slot, then the same prompt again: the
     # second admission maps its pages from the prefix index and skips the
@@ -189,14 +190,38 @@ def test_prefill_token_rows(model):
         eng.admit(eng.schedule_admissions(0.0), 0.0)
     assert eng.prefill_chunks_skipped == 2
     assert eng.prefill_tokens == 300 + 600
-    assert eng.prefill_token_rows == 2 * 256 + 2 * 384 + 2 * 128
+    assert eng.prefill_token_rows == 128 + 256 + 384 + 128
     # the second copy computes only its last chunk's 300 - 256 tokens
     assert eng.prefill_tokens_computed == 300 + 300 + 44
     from benchmarks.chip import program_trace
     assert program_trace.prefill_pad_share(eng) == pytest.approx(
-        100 * (1 - 644 / 1536))
+        100 * (1 - 644 / 896))
     eng.reset()
     assert eng.prefill_tokens_computed == 0
+
+
+def test_one_request_warm_leaves_no_compile(model):
+    """Warmed as the chip benchmark warms (one request as long as the
+    longest prompt, then ``reset``), the engine compiles nothing when it
+    then admits a pair and a lone request whose longest prompt ends in
+    each chunk: every admission prefills one row a request."""
+    cfg, params = model
+    eng = _engine(cfg, params)
+    eng.enqueue(_req(0, 500, max_new=2))
+    _drain(eng)
+    eng.reset()
+    rid = 1
+    with tracing.CompileCounter() as cc:
+        for plen in (100, 200, 300, 500):
+            for group in ((plen, plen // 2), (plen,)):
+                for n in group:
+                    eng.enqueue(_req(rid, n))
+                    rid += 1
+                _drain(eng)
+    assert cc.n == 0, dict(cc.programs)
+    assert eng.prefill_one_row_admissions == 12
+    assert eng.prefill_token_rows == (128 + 128) + (256 + 128) + (
+        384 + 256) + (512 + 256) + 1280
 
 
 def test_compile_counter_counts_fresh_programs_once():
